@@ -1,5 +1,5 @@
-"""Measured PIM-engine performance (the §Perf hillclimb that runs for real
-on this container).
+"""Measured PIM-engine performance on the device JAX runs on; every row
+and the report name it (``platform``, ``device_kind``, ``device_count``).
 
 Two views:
 
@@ -20,11 +20,19 @@ from __future__ import annotations
 import json
 import time
 
+import jax
 import numpy as np
 
 import repro.workloads as wl
 from repro.core import compile_cache, engine
 from repro.core.config import DPUConfig
+
+
+def device_info() -> dict:
+    """The device every timing of this run was taken on."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(jax.devices())}
 
 
 def _setup(name: str, scale: float, n_threads: int, mram_bytes=1 << 21,
@@ -134,27 +142,29 @@ def main():
     ap.add_argument("--min-speedup", type=float, default=1.0,
                     help="with --check: required cold/warm ratio")
     args = ap.parse_args()
+    compile_cache.use_persistent_cache()
+    device = device_info()
 
     print("== launch latency: cold (compile) vs warm (cache hit) ==")
-    lat = launch_latency("VA", args.launch_scale)
+    lat = {**launch_latency("VA", args.launch_scale), **device}
     print(lat)
     print("== subset launches sharing one DPU bucket ==")
-    sub = subset_reuse("VA", args.launch_scale)
+    sub = {**subset_reuse("VA", args.launch_scale), **device}
     print(sub)
     print("== steady-state engine throughput ==")
     rows = []
     for d in (1, 4, 16, 64):
-        r = steady_state("VA", args.scale, n_dpus=d)
+        r = {**steady_state("VA", args.scale, n_dpus=d), **device}
         rows.append(r)
         print(r)
     for skip in (False, True):
-        r = steady_state("BS", args.scale, n_dpus=1, event_skip=skip)
-        r["event_skip"] = skip
+        r = {**steady_state("BS", args.scale, n_dpus=1, event_skip=skip),
+             "event_skip": skip, **device}
         rows.append(r)
         print(r)
 
-    report = {"launch": lat, "subset_reuse": sub, "steady_state": rows,
-              "cache": compile_cache.stats()}
+    report = {"device": device, "launch": lat, "subset_reuse": sub,
+              "steady_state": rows, "cache": compile_cache.stats()}
     if args.json:
         with open(args.json, "w") as f:
             json.dump(report, f, indent=2)

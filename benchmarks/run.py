@@ -8,6 +8,8 @@ async-pipeline, ``lm`` serving roofline, ``faults`` fault-injection
 availability/goodput, ``cluster`` multi-tenant cluster runtime,
 ``all``); ``--only`` further filters by substring — a filter matching
 nothing is an error listing the valid bench names, not a silent no-op.
+A bench that raises prints an ``error`` row, and the run exits non-zero
+once every selected bench has run.
 
 ``--trace PATH`` runs the selected benches under a process-wide
 :class:`repro.obs.Tracer` (every :class:`PIMSystem` any suite builds
@@ -58,6 +60,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.check and not args.trace:
         ap.error("--check requires --trace")
+    from repro.core import compile_cache
+    compile_cache.use_persistent_cache()
 
     tracer = profile = None
     if args.trace:
@@ -153,12 +157,15 @@ def main() -> None:
             + (f" --only {args.only!r}" if args.only else "")
             + f"; valid names: {valid}")
 
+    failed = []
     for name, fn in selected.items():
         t0 = time.time()
         try:
             rows = fn()
         except Exception as e:  # noqa: BLE001
+            # keep going so every bench reports; the run fails below
             rows = [{"error": f"{type(e).__name__}: {e}"}]
+            failed.append(name)
         _emit(name, time.time() - t0, rows)
 
     if tracer is not None:
@@ -176,6 +183,9 @@ def main() -> None:
                 raise SystemExit("trace/timeline mismatch:\n"
                                  + "\n".join(errors))
             print(f"# check: OK ({len(tracer.systems)} systems consistent)")
+    if failed:
+        raise SystemExit(f"{len(failed)} bench(es) failed: "
+                         + ", ".join(failed))
 
 
 if __name__ == "__main__":

@@ -30,12 +30,16 @@ a full state copy per step-loop entry.
 Knobs: :data:`PROGRAM_BUCKET_FLOOR` / :data:`DPU_BUCKET_FLOOR` set the
 smallest bucket (smaller floors = tighter shapes but more executables).
 :func:`prewarm` compiles ahead of time; :func:`stats` exposes the
-hit/miss/compile counters the tests assert on.
+hit/miss/compile counters the tests assert on.  Entry points (never the
+library or the tests) call :func:`use_persistent_cache` so compiled
+executables also survive the process.
 """
 from __future__ import annotations
 
+import os
 import threading
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Callable, Dict, Optional
 
 import jax
@@ -51,6 +55,26 @@ from repro.core.config import DPUConfig
 PROGRAM_BUCKET_FLOOR = 64
 #: smallest padded DPU-axis width
 DPU_BUCKET_FLOOR = 1
+
+
+#: persistent XLA cache used when ``JAX_COMPILATION_CACHE_DIR`` is unset —
+#: a fixed path in the checkout, so each run finds the last one's entries
+DEFAULT_PERSISTENT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    JAX already reads ``JAX_COMPILATION_CACHE_DIR`` when it is set, and
+    then no other directory is configured here.  Otherwise the cache goes
+    to :data:`DEFAULT_PERSISTENT_CACHE`.  Called by the entry-point
+    scripts only, so importing the library changes no JAX setting."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir",
+                      str(DEFAULT_PERSISTENT_CACHE))
+    return str(DEFAULT_PERSISTENT_CACHE)
 
 
 def pow2_bucket(n: int, floor: int = 1) -> int:

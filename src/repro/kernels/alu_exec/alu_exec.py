@@ -47,7 +47,7 @@ def _alu_kernel(op_ref, a_ref, b_ref, o_ref):
     o_ref[...] = jnp.where((op >= 0) & (op < len(results)), out, 0)
 
 
-def alu_exec_2d(op, a, b, *, interpret=True):
+def alu_exec_2d(op, a, b, *, interpret=False):
     """op/a/b: (R, 128) int32 with R a multiple of 8."""
     R = op.shape[0]
     assert op.shape == a.shape == b.shape and op.shape[1] == TILE[1]

@@ -12,7 +12,7 @@ _LANE = TILE[0] * TILE[1]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def alu_exec(op, a, b, *, interpret=True):
+def alu_exec(op, a, b, *, interpret=False):
     """Flat (N,) int32 op/a/b -> (N,) int32 results via the Pallas kernel."""
     n = op.shape[0]
     pad = (-n) % _LANE

@@ -65,7 +65,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, causal, window,
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, bq=128, bk=128,
-                    interpret=True):
+                    interpret=False):
     """q: (B,S,H,Dk)  k: (B,S,KV,Dk)  v: (B,S,KV,Dv) -> (B,S,H,Dv)."""
     B, S, H, Dk = q.shape
     KV = k.shape[2]

@@ -10,7 +10,7 @@ from repro.kernels.ssd_scan.ssd_scan import ssd_chunk
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan_op(x, dt, A, Bm, Cm, *, chunk=64, interpret=True):
+def ssd_scan_op(x, dt, A, Bm, Cm, *, chunk=64, interpret=False):
     """Full sequence scan.  x: (BH, S, P)  dt: (BH, S)  A: (BH,)
     Bm/Cm: (BH, S, N) -> (y (BH, S, P), final_state (BH, N, P))."""
     BH, S, P = x.shape

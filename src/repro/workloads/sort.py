@@ -40,8 +40,9 @@ from repro.workloads.streaming import _min_imm
 SORT_MAX_N = 4096
 #: max packed received words the merge kernel stages into WRAM
 MERGE_MAX_WORDS = 6144
-#: max DPUs (the merge kernel's count/cursor arrays are sized for this)
-MAX_D = 32
+#: max DPUs, one full UPMEM rank (the merge kernel's count/cursor arrays
+#: are sized for this)
+MAX_D = 64
 #: splitter samples contributed per DPU
 SAMPLES = 8
 

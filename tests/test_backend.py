@@ -7,24 +7,7 @@ not change a single simulated value."""
 import pytest
 
 from repro.core import backend as backends
-from repro.core.config import DPUConfig
-from repro.core.host import PIMSystem
-from repro.workloads import get
-
-# (workload, cfg kwargs, n_threads) -> pre-refactor goldens
-GOLDENS = {
-    # cycles, issued, timeline.total, timeline.kernel
-    "VA-scalar": (5336, 11488, 4.131521235521236e-05,
-                  1.5245714285714286e-05),
-    "VA-simt": (2133, 11488, 3.216378378378378e-05,
-                6.094285714285714e-06),
-    "BFS-scalar": (68900, 30916, 0.00027344401544401544,
-                   0.00019685714285714285),
-}
-
-
-def _cfg(**kw):
-    return DPUConfig(n_dpus=4, n_ranks=2, n_channels=2, **kw)
+from repro.goldens import GOLDENS, golden_config as _cfg, run_golden
 
 
 # ---------------------------------------------------------------------------
@@ -84,12 +67,9 @@ def test_simt_backend_validates_width():
 
 @pytest.mark.parametrize("name", sorted(GOLDENS))
 def test_bit_exact_vs_pre_refactor(name):
-    wl_name, be = name.split("-")
-    kw = {"simt_width": 4} if be == "simt" else {}
-    system = PIMSystem(_cfg(**kw))
-    _, rep = get(wl_name).run(system, 8, scale=0.02, seed=0)
-    cycles, issued, total, kernel = GOLDENS[name]
-    assert rep.cycles == cycles
-    assert rep.issued == issued
-    assert system.timeline.total == total
-    assert system.timeline.kernel == kernel
+    cycles, issued, total, kernel = run_golden(name)
+    want_cycles, want_issued, want_total, want_kernel = GOLDENS[name]
+    assert cycles == want_cycles
+    assert issued == want_issued
+    assert total == want_total
+    assert kernel == want_kernel

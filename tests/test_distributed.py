@@ -4,15 +4,9 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 _SCRIPT = os.path.join(os.path.dirname(__file__), "_dist_scenarios.py")
-
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType unavailable in this jax version; "
-    "_dist_scenarios.py needs it")
 
 
 @pytest.mark.slow

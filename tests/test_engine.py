@@ -1,11 +1,7 @@
 """Cycle-level engine: semantics + microarchitectural timing properties."""
 import numpy as np
 import pytest
-
-pytest.importorskip(
-    "hypothesis", reason="hypothesis not installed; skipping engine "
-    "property tests (pip install -e .[test])")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.core import engine
 from repro.core.asm import Program, Reg, TID, ZERO

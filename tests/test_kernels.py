@@ -3,11 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-pytest.importorskip(
-    "hypothesis", reason="hypothesis not installed; skipping kernel "
-    "property tests (pip install -e .[test])")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.alu_exec.ops import alu_exec
 from repro.kernels.alu_exec.ref import alu_exec_ref
@@ -30,7 +26,7 @@ def test_alu_kernel_shapes(n):
                     .astype(np.int32))
     b = jnp.asarray(rng.integers(-2**31, 2**31 - 1, n, dtype=np.int64)
                     .astype(np.int32))
-    assert (alu_exec(op, a, b) == alu_exec_ref(op, a, b)).all()
+    assert (alu_exec(op, a, b, interpret=True) == alu_exec_ref(op, a, b)).all()
 
 
 @settings(max_examples=25, deadline=None)
@@ -40,14 +36,14 @@ def test_alu_kernel_hypothesis(op, a, b):
     opv = jnp.full((8,), op, jnp.int32)
     av = jnp.full((8,), a, jnp.int32)
     bv = jnp.full((8,), b, jnp.int32)
-    assert (alu_exec(opv, av, bv) == alu_exec_ref(opv, av, bv)).all()
+    assert (alu_exec(opv, av, bv, interpret=True) == alu_exec_ref(opv, av, bv)).all()
 
 
 def test_alu_edge_cases():
     cases = [(9, -2**31, -1), (9, 5, 0), (5, 1, 33), (7, -8, 1),
              (8, 2**30, 2)]
     op, a, b = map(lambda t: jnp.asarray(t, jnp.int32), zip(*cases))
-    assert (alu_exec(op, a, b) == alu_exec_ref(op, a, b)).all()
+    assert (alu_exec(op, a, b, interpret=True) == alu_exec_ref(op, a, b)).all()
 
 
 def test_alu_nonalu_opcodes_return_zero():
@@ -56,7 +52,7 @@ def test_alu_nonalu_opcodes_return_zero():
     op = jnp.asarray([12, 16, 28, 30, -1], jnp.int32)
     a = jnp.asarray([5, 6, 7, 8, 9], jnp.int32)
     b = jnp.asarray([1, 2, 3, 4, 5], jnp.int32)
-    got = alu_exec(op, a, b)
+    got = alu_exec(op, a, b, interpret=True)
     assert (got == alu_exec_ref(op, a, b)).all()
     assert (got == 0).all()
 
@@ -79,7 +75,7 @@ def test_flash_kernel_vs_ref(s, h, kv, dk, dv, causal, window):
     k = jax.random.normal(ks[1], (2, s, kv, dk), jnp.float32)
     v = jax.random.normal(ks[2], (2, s, kv, dv), jnp.float32)
     got = flash_attention_op(q, k, v, causal=causal, window=window,
-                             bq=64, bk=64)
+                             bq=64, bk=64, interpret=True)
     want = attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -90,7 +86,8 @@ def test_flash_kernel_bf16():
     q = jax.random.normal(ks[0], (1, 128, 4, 32), jnp.bfloat16)
     k = jax.random.normal(ks[1], (1, 128, 4, 32), jnp.bfloat16)
     v = jax.random.normal(ks[2], (1, 128, 4, 32), jnp.bfloat16)
-    got = flash_attention_op(q, k, v, bq=64, bk=64).astype(jnp.float32)
+    got = flash_attention_op(q, k, v, bq=64, bk=64,
+                             interpret=True).astype(jnp.float32)
     want = attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
                          v.astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -104,7 +101,7 @@ def test_flash_matches_model_blocked_attention():
     q = jax.random.normal(ks[0], (2, 256, 8, 32), jnp.float32)
     k = jax.random.normal(ks[1], (2, 256, 2, 32), jnp.float32)
     v = jax.random.normal(ks[2], (2, 256, 2, 32), jnp.float32)
-    a = flash_attention_op(q, k, v, bq=64, bk=64)
+    a = flash_attention_op(q, k, v, bq=64, bk=64, interpret=True)
     b = blocked_attention(q, k, v, q_chunk=128, kv_chunk=64)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
@@ -126,7 +123,7 @@ def test_ssd_kernel_vs_sequential_ref(s, p, n, chunk):
     A = -jnp.exp(jax.random.normal(ks[2], (bh,)))
     Bm = jax.random.normal(ks[3], (bh, s, n))
     Cm = jax.random.normal(ks[4], (bh, s, n))
-    y, state = ssd_scan_op(x, dt, A, Bm, Cm, chunk=chunk)
+    y, state = ssd_scan_op(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     for h in range(bh):
         yw, sw = ssd_chunk_ref(x[h], dt[h], A[h], Bm[h], Cm[h],
                                jnp.zeros((n, p)))
@@ -153,7 +150,7 @@ def test_ssd_kernel_matches_model_path():
     Ak = jnp.tile(A, B)
     Bk = Bm.transpose(0, 2, 1, 3).reshape(B * H, S, N)
     Ck = Cm.transpose(0, 2, 1, 3).reshape(B * H, S, N)
-    y_k, st_k = ssd_scan_op(xk, dtk, Ak, Bk, Ck, chunk=16)
+    y_k, st_k = ssd_scan_op(xk, dtk, Ak, Bk, Ck, chunk=16, interpret=True)
     y_k = y_k.reshape(B, H, S, P).transpose(0, 2, 1, 3)
     st_k = st_k.reshape(B, H, N, P)
     np.testing.assert_allclose(np.asarray(y_model), np.asarray(y_k),
