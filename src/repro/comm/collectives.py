@@ -18,9 +18,12 @@ schedule instead of serializing on whole-channel resources.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.obs import spans
 
 OPS: Dict[str, Callable] = {
     "sum": np.add,
@@ -29,6 +32,16 @@ OPS: Dict[str, Callable] = {
     "or": np.bitwise_or,
     "and": np.bitwise_and,
 }
+
+
+def _spanned(fn):
+    """Run a public collective inside one ``repro.comm.collective`` host
+    span: the payload exchange and its pricing."""
+    @functools.wraps(fn)
+    def collective(*args, **kw):
+        with spans.span(spans.COMM_COLLECTIVE, kind=fn.__name__):
+            return fn(*args, **kw)
+    return collective
 
 
 def _charge(system, kind: str, seconds: float, nbytes: float, ranks=None,
@@ -118,6 +131,7 @@ def _commit(mram, idx, view):
             mram[d, :view.shape[1]] = view[i]
 
 
+@_spanned
 def broadcast(system, mram: np.ndarray, off: int, n: int, root: int = 0,
               dpus: Optional[Sequence[int]] = None):
     """Replicate ``n`` words at ``off`` from DPU ``root`` to all DPUs."""
@@ -134,6 +148,7 @@ def broadcast(system, mram: np.ndarray, off: int, n: int, root: int = 0,
     _commit(mram, idx, view)
 
 
+@_spanned
 def scatter(system, mram: np.ndarray, src_off: int, dst_off: int,
             n_per_dpu: int, root: int = 0,
             dpus: Optional[Sequence[int]] = None):
@@ -159,6 +174,7 @@ def scatter(system, mram: np.ndarray, src_off: int, dst_off: int,
     _commit(mram, idx, view)
 
 
+@_spanned
 def gather(system, mram: np.ndarray, src_off: int, dst_off: int,
            n_per_dpu: int, root: int = 0,
            dpus: Optional[Sequence[int]] = None):
@@ -182,6 +198,7 @@ def gather(system, mram: np.ndarray, src_off: int, dst_off: int,
     _commit(mram, idx, view)
 
 
+@_spanned
 def reduce(system, mram: np.ndarray, off: int, n: int, op: str = "sum",
            root: int = 0, dpus: Optional[Sequence[int]] = None):
     """Combine ``n`` words at ``off`` across DPUs onto ``root``."""
@@ -199,6 +216,7 @@ def reduce(system, mram: np.ndarray, off: int, n: int, op: str = "sum",
     _commit(mram, idx, view)
 
 
+@_spanned
 def allreduce(system, mram: np.ndarray, off: int, n: int, op: str = "sum",
               dpus: Optional[Sequence[int]] = None):
     """Combine ``n`` words at ``off`` across DPUs; all DPUs get the result."""
@@ -215,6 +233,7 @@ def allreduce(system, mram: np.ndarray, off: int, n: int, op: str = "sum",
     _commit(mram, idx, view)
 
 
+@_spanned
 def allgather(system, mram: np.ndarray, src_off: int, dst_off: int,
               n_per_dpu: int, dpus: Optional[Sequence[int]] = None):
     """Every DPU ends with the concatenation of all shards at ``dst_off``."""
@@ -235,6 +254,7 @@ def allgather(system, mram: np.ndarray, src_off: int, dst_off: int,
     _commit(mram, idx, view)
 
 
+@_spanned
 def alltoall(system, mram: np.ndarray, src_off: int, dst_off: int,
              n_per_pair: int, dpus: Optional[Sequence[int]] = None):
     """Transpose: DPU d's j-th ``n_per_pair``-word block goes to DPU j's

@@ -27,12 +27,19 @@ The cache kills that three ways:
 State buffers are donated to XLA (they are rebuilt per launch), avoiding
 a full state copy per step-loop entry.
 
+The jitted driver is named after its backend, ``pim_engine_<backend>``,
+so its executable reads ``jit_pim_engine_scalar`` (etc.) in a profiler
+trace.  Each launch is a ``repro.launch`` host span with four children
+(``prepare``, ``upload``, ``device``, ``readback``; see
+:mod:`repro.obs.spans`).
+
 Knobs: :data:`PROGRAM_BUCKET_FLOOR` / :data:`DPU_BUCKET_FLOOR` set the
 smallest bucket (smaller floors = tighter shapes but more executables).
 :func:`prewarm` compiles ahead of time; :func:`stats` exposes the
-hit/miss/compile counters the tests assert on.  Entry points (never the
-library or the tests) call :func:`use_persistent_cache` so compiled
-executables also survive the process.
+hit/miss counters the tests assert on, the engine's loop iterations and
+the bytes uploaded per launch.  Entry points (never the library or the
+tests) call :func:`use_persistent_cache` so compiled executables also
+survive the process.
 """
 from __future__ import annotations
 
@@ -40,7 +47,7 @@ import os
 import threading
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +57,7 @@ from repro.core import backend as backends
 from repro.core import engine
 from repro.core.backend import resolve_backend
 from repro.core.config import DPUConfig
+from repro.obs import spans
 
 #: smallest padded program length (instruction slots)
 PROGRAM_BUCKET_FLOOR = 64
@@ -117,21 +125,29 @@ _LOCK = threading.Lock()
 _ENTRIES: Dict[tuple, _Entry] = {}
 _HITS = 0
 _MISSES = 0
+_LOOP_ITERS = 0
+_H2D_BYTES = 0
 
 
 def _make_go(cfg: DPUConfig, be: "backends.ExecBackend", T: int) -> Callable:
+    """The jitted driver: ``(ir, state) -> (final state, loop iterations)``.
+    The iteration count rides beside the state, never inside it."""
     step, cond = be.step_driver(cfg, T)
 
     def drive(ir, st):
-        return jax.lax.while_loop(cond, lambda s: step(ir, s), st)
+        return jax.lax.while_loop(lambda c: cond(c[0]),
+                                  lambda c: (step(ir, c[0]), c[1] + 1),
+                                  (st, jnp.int32(0)))
 
+    drive.__name__ = drive.__qualname__ = f"pim_engine_{be.name}"
     # the state is rebuilt per launch -> donate it; the instruction image
     # is reused across launches -> never donated
     return jax.jit(drive, donate_argnums=(1,))
 
 
 def _get_entry(cfg: DPUConfig, be: "backends.ExecBackend", P: int, Dp: int,
-               T: int, M: int) -> _Entry:
+               T: int, M: int) -> Tuple[_Entry, bool]:
+    """The cached executable for this shape, and whether it was a hit."""
     global _HITS, _MISSES
     key = (be.name, be.static_key(cfg), P, Dp, T, M)
     with _LOCK:
@@ -140,9 +156,9 @@ def _get_entry(cfg: DPUConfig, be: "backends.ExecBackend", P: int, Dp: int,
             _MISSES += 1
             entry = _Entry(go=_make_go(cfg, be, T), key=key)
             _ENTRIES[key] = entry
-        else:
-            _HITS += 1
-        return entry
+            return entry, False
+        _HITS += 1
+        return entry, True
 
 
 def _padded_state(cfg: DPUConfig, be: "backends.ExecBackend", binary,
@@ -168,25 +184,54 @@ def _padded_state(cfg: DPUConfig, be: "backends.ExecBackend", binary,
         be.set_ndpus(st, D, ndpus_reg)
     if all_done:
         be.finish_all(st)
-    return jax.tree_util.tree_map(jnp.asarray, st)
+    return st
+
+
+def _nbytes(tree) -> int:
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
 
 
 def _launch(cfg: DPUConfig, binary, wram_init, mram_init, T: int,
             be: "backends.ExecBackend", pad: bool, all_done: bool = False,
             ndpus_reg: int = None):
-    be.validate(cfg, binary, T)
-    wram_init = np.ascontiguousarray(np.asarray(wram_init, np.int32))
-    mram_init = np.ascontiguousarray(np.asarray(mram_init, np.int32))
-    capacity = binary.opcode.shape[0]
-    P = program_bucket(binary.n_instrs, capacity) if pad else capacity
-    Dp = dpu_bucket(cfg.n_dpus) if pad else cfg.n_dpus
-    st0 = _padded_state(cfg, be, binary, wram_init, mram_init, T, Dp,
-                        all_done=all_done, ndpus_reg=ndpus_reg)
-    entry = _get_entry(cfg, be, P, Dp, T, mram_init.shape[1])
-    ir = tuple(jnp.asarray(a[:P]) for a in binary.arrays)
-    out = entry.go(ir, st0)
+    """Build, upload and run one launch.  Returns ``(entry, cache hit,
+    final device state, loop iterations as a device scalar)``, the
+    device work finished."""
+    global _H2D_BYTES
+    with spans.span(spans.LAUNCH_PREPARE):
+        be.validate(cfg, binary, T)
+        wram_init = np.ascontiguousarray(np.asarray(wram_init, np.int32))
+        mram_init = np.ascontiguousarray(np.asarray(mram_init, np.int32))
+        capacity = binary.opcode.shape[0]
+        P = program_bucket(binary.n_instrs, capacity) if pad else capacity
+        Dp = dpu_bucket(cfg.n_dpus) if pad else cfg.n_dpus
+        st0 = _padded_state(cfg, be, binary, wram_init, mram_init, T, Dp,
+                            all_done=all_done, ndpus_reg=ndpus_reg)
+        entry, hit = _get_entry(cfg, be, P, Dp, T, mram_init.shape[1])
+        ir = tuple(a[:P] for a in binary.arrays)
+    nbytes = _nbytes(st0) + _nbytes(ir)
+    with spans.span(spans.LAUNCH_UPLOAD, nbytes=nbytes):
+        st0 = jax.tree_util.tree_map(jnp.asarray, st0)
+        ir = tuple(jnp.asarray(a) for a in ir)
+    with _LOCK:
+        _H2D_BYTES += nbytes
+    with spans.span(spans.LAUNCH_DEVICE):
+        out, iters = entry.go(ir, st0)
+        jax.block_until_ready(out)
     entry.launches += 1
-    return entry, out
+    return entry, hit, out, iters
+
+
+def _count_iters(iters) -> None:
+    global _LOOP_ITERS
+    n = int(iters)
+    with _LOCK:
+        _LOOP_ITERS += n
+
+
+def _launch_span(cfg: DPUConfig, be: "backends.ExecBackend"):
+    return spans.span(spans.LAUNCH, sim_id=spans.sim_id(), backend=be.name,
+                      dpus=cfg.n_dpus)
 
 
 def run(cfg: DPUConfig, binary, wram_init, mram_init, n_threads: int = None,
@@ -210,11 +255,15 @@ def run(cfg: DPUConfig, binary, wram_init, mram_init, n_threads: int = None,
     logical ``cfg.n_dpus`` rows."""
     be = backends.get(resolve_backend(cfg, backend))
     T = n_threads or cfg.n_tasklets
-    _, out = _launch(cfg, binary, wram_init, mram_init, T, be, pad,
-                     ndpus_reg=ndpus_reg)
-    out = jax.tree_util.tree_map(np.asarray, out)
-    if out["status"].shape[0] != cfg.n_dpus:
-        out = jax.tree_util.tree_map(lambda x: x[:cfg.n_dpus], out)
+    with _launch_span(cfg, be) as launch_span:
+        _, hit, out, iters = _launch(cfg, binary, wram_init, mram_init, T,
+                                     be, pad, ndpus_reg=ndpus_reg)
+        launch_span.set_metadata(cache="hit" if hit else "miss")
+        with spans.span(spans.LAUNCH_READBACK, nbytes=_nbytes(out)):
+            out = jax.tree_util.tree_map(np.asarray, out)
+            if out["status"].shape[0] != cfg.n_dpus:
+                out = jax.tree_util.tree_map(lambda x: x[:cfg.n_dpus], out)
+            _count_iters(iters)
     return out
 
 
@@ -232,9 +281,11 @@ def prewarm(cfg: DPUConfig, binary, mram_words: int = None,
     M = mram_words or cfg.mram_words
     wram = np.zeros((cfg.n_dpus, 1), np.int32)
     mram = np.zeros((cfg.n_dpus, M), np.int32)
-    entry, out = _launch(cfg, binary, wram, mram, T, be, pad=True,
-                         all_done=True)
-    jax.block_until_ready(out)
+    with _launch_span(cfg, be) as launch_span:
+        entry, hit, _, iters = _launch(cfg, binary, wram, mram, T, be, True,
+                                       all_done=True)
+        launch_span.set_metadata(cache="hit" if hit else "miss")
+        _count_iters(iters)
     return entry.key
 
 
@@ -244,14 +295,20 @@ def prewarm(cfg: DPUConfig, binary, mram_words: int = None,
 
 
 def stats() -> Dict[str, int]:
-    """Cache counters.  ``misses`` counts executable *builds* — a
-    same-shape relaunch must leave it unchanged."""
+    """Cumulative counters.  ``misses`` counts executable *builds* — a
+    same-shape relaunch must leave it unchanged.  ``loop_iters`` counts
+    the engine's ``while_loop`` iterations (one may advance several
+    simulated cycles under ``event_skip``; an all-``DONE`` prewarm adds
+    none), ``h2d_bytes`` the bytes uploaded to the device (state leaves
+    plus instruction image)."""
     with _LOCK:
         return {
             "entries": len(_ENTRIES),
             "hits": _HITS,
             "misses": _MISSES,
             "launches": sum(e.launches for e in _ENTRIES.values()),
+            "loop_iters": _LOOP_ITERS,
+            "h2d_bytes": _H2D_BYTES,
         }
 
 
@@ -265,8 +322,7 @@ def cache_info():
 
 def clear():
     """Drop every cached executable and zero the counters (tests)."""
-    global _HITS, _MISSES
+    global _HITS, _MISSES, _LOOP_ITERS, _H2D_BYTES
     with _LOCK:
         _ENTRIES.clear()
-        _HITS = 0
-        _MISSES = 0
+        _HITS = _MISSES = _LOOP_ITERS = _H2D_BYTES = 0

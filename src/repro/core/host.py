@@ -35,7 +35,7 @@ from repro.core.config import DPUConfig
 from repro.core.isa import Binary
 from repro.faults.model import DpuFaultError, FaultPlan, FaultReport
 from repro.faults.retry import DEFAULT_POLICY, RetryPolicy
-from repro.obs import get_default_tracer
+from repro.obs import get_default_tracer, spans
 from repro.obs.tracer import PID_HOST, Tracer
 from repro.sched import queue as sq
 from repro.sched import scheduler as ssched
@@ -327,18 +327,19 @@ class PIMSystem:
         stamp ``timeline.elapsed`` with its makespan.  The configured
         ``channel_contention`` prices concurrent operations sharing a
         physical channel (or the fabric) on disjoint rank shares."""
-        sched = ssched.schedule(self.runtime.queues,
-                                contention=self.cfg.channel_contention)
-        self.timeline.elapsed = sched.makespan
-        self.last_schedule = sched
-        if self.recorder is not None:
-            self.recorder.on_sync()
-        if self.tracer is not None:
-            # re-ingest under this system's key: sync() re-resolves the
-            # whole submission history, so replacement keeps the trace
-            # covering every command exactly once
-            self.tracer.ingest_schedule(sched, key=id(self),
-                                        pid=self.tracer.pid_of(self))
+        with spans.span(spans.SCHED_SYNC):
+            sched = ssched.schedule(self.runtime.queues,
+                                    contention=self.cfg.channel_contention)
+            self.timeline.elapsed = sched.makespan
+            self.last_schedule = sched
+            if self.recorder is not None:
+                self.recorder.on_sync()
+            if self.tracer is not None:
+                # re-ingest under this system's key: sync() re-resolves the
+                # whole submission history, so replacement keeps the trace
+                # covering every command exactly once
+                self.tracer.ingest_schedule(sched, key=id(self),
+                                            pid=self.tracer.pid_of(self))
         return sched
 
     # ---- transfer accounting -------------------------------------------------
@@ -368,47 +369,49 @@ class PIMSystem:
         recorder's re-pricing metadata; fault-degraded attempts drop it
         (their seconds carry a sampled factor a replay cannot re-derive,
         so they replay as recorded)."""
-        res = self._chan_resources(ev)
-        if self.faults is None:
-            return self._submit(kind, phase, label, ev.seconds,
-                                ev.total_bytes, res, meta=spec)
-        xfer = self._xfer_idx
-        self._xfer_idx += 1
-        policy = self.retry or DEFAULT_POLICY
-        for attempt in range(policy.max_attempts):
-            out = self.faults.link_outcome(xfer, attempt)
-            secs = ev.seconds * out.factor
-            timed_out = out.timeout or (policy.timeout_seconds is not None
-                                        and secs > policy.timeout_seconds)
-            if not timed_out:
-                if out.factor > 1.0:
-                    self._log_fault(FaultReport(
-                        kind="link", label=label, launch=xfer,
-                        attempt=attempt,
-                        detail=f"degraded x{out.factor:g}"))
-                scaled = {r: b * out.factor for r, b in res.items()}
-                return self._submit(kind, phase, label, secs,
-                                    ev.total_bytes, scaled, attempt=attempt)
-            # hung attempt: the host notices at the timeout (or, with no
-            # timeout configured, after the full degraded duration)
-            waste = secs if policy.timeout_seconds is None \
-                else min(secs, policy.timeout_seconds)
-            self._log_fault(FaultReport(
-                kind="link", label=label, launch=xfer, attempt=attempt,
-                detail="timeout", wasted_seconds=waste))
-            self._charge_retry(kind, label,
-                               waste, {r: min(b * out.factor, waste)
-                                       for r, b in res.items()},
-                               attempt, nbytes=ev.total_bytes)
-            backoff = policy.backoff_after(attempt)
-            if backoff > 0.0:
-                self._charge_retry(kind, f"{label}:backoff", backoff, {},
-                                   attempt)
-        raise DpuFaultError(FaultReport(
-            kind="retry_exhausted", label=label, launch=xfer,
-            attempt=policy.max_attempts,
-            detail=f"transfer timed out on all {policy.max_attempts} "
-                   "attempts"))
+        with spans.span(spans.COMM_TRANSFER, kind=kind):
+            res = self._chan_resources(ev)
+            if self.faults is None:
+                return self._submit(kind, phase, label, ev.seconds,
+                                    ev.total_bytes, res, meta=spec)
+            xfer = self._xfer_idx
+            self._xfer_idx += 1
+            policy = self.retry or DEFAULT_POLICY
+            for attempt in range(policy.max_attempts):
+                out = self.faults.link_outcome(xfer, attempt)
+                secs = ev.seconds * out.factor
+                timed_out = out.timeout or (policy.timeout_seconds is not None
+                                            and secs > policy.timeout_seconds)
+                if not timed_out:
+                    if out.factor > 1.0:
+                        self._log_fault(FaultReport(
+                            kind="link", label=label, launch=xfer,
+                            attempt=attempt,
+                            detail=f"degraded x{out.factor:g}"))
+                    scaled = {r: b * out.factor for r, b in res.items()}
+                    return self._submit(kind, phase, label, secs,
+                                        ev.total_bytes, scaled,
+                                        attempt=attempt)
+                # hung attempt: the host notices at the timeout (or, with no
+                # timeout configured, after the full degraded duration)
+                waste = secs if policy.timeout_seconds is None \
+                    else min(secs, policy.timeout_seconds)
+                self._log_fault(FaultReport(
+                    kind="link", label=label, launch=xfer, attempt=attempt,
+                    detail="timeout", wasted_seconds=waste))
+                self._charge_retry(kind, label,
+                                   waste, {r: min(b * out.factor, waste)
+                                           for r, b in res.items()},
+                                   attempt, nbytes=ev.total_bytes)
+                backoff = policy.backoff_after(attempt)
+                if backoff > 0.0:
+                    self._charge_retry(kind, f"{label}:backoff", backoff, {},
+                                       attempt)
+            raise DpuFaultError(FaultReport(
+                kind="retry_exhausted", label=label, launch=xfer,
+                attempt=policy.max_attempts,
+                detail=f"transfer timed out on all {policy.max_attempts} "
+                       "attempts"))
 
     def collective(self, kind: str, seconds: float, nbytes: float,
                    ranks: Optional[Sequence[int]] = None,
@@ -441,13 +444,14 @@ class PIMSystem:
                        phase: str = "kernel") -> "sq.Command":
         """Charge one successful kernel: hold the involved ranks' compute
         slots (no fault handling — the caller already resolved that)."""
-        meta = {"price": "kernel", "freq_mhz": self.cfg.freq_mhz,
-                "ranks": None if ranks is None
-                else [int(r) for r in self._ranks_or_all(ranks)]}
-        return self._submit(
-            sq.LAUNCH, phase, name, seconds, 0.0,
-            {f"rank{r}": seconds for r in self._ranks_or_all(ranks)},
-            meta=meta)
+        with spans.span(spans.HOST_REPORT):
+            meta = {"price": "kernel", "freq_mhz": self.cfg.freq_mhz,
+                    "ranks": None if ranks is None
+                    else [int(r) for r in self._ranks_or_all(ranks)]}
+            return self._submit(
+                sq.LAUNCH, phase, name, seconds, 0.0,
+                {f"rank{r}": seconds for r in self._ranks_or_all(ranks)},
+                meta=meta)
 
     def modeled_launch(self, name: str, seconds: float,
                        ranks: Optional[Sequence[int]] = None,
@@ -609,7 +613,8 @@ class PIMSystem:
             raise RuntimeError(
                 f"{name}: kernel hit max_cycles={cfg.max_cycles} "
                 f"(status={np.unique(st['status'])})")
-        rep = be.report(name, cfg, st, T)
+        with spans.span(spans.HOST_REPORT):
+            rep = be.report(name, cfg, st, T)
         return st, rep, ranks
 
     def _launch_faulty(self, name: str, binary: Binary, args, mram, T: int,

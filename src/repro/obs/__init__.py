@@ -25,6 +25,20 @@ process-wide for code you don't construct systems in yourself
         run_benchmark()
     t.finalize()                     # sync any un-synced system
     t.save("run.trace.json")         # open in ui.perfetto.dev
+
+Host spans.  The tracer's clock is modelled time.  Where the *host's*
+wall clock goes is recorded separately, by :mod:`repro.obs.spans`:
+``jax.profiler.TraceAnnotation`` spans at the simulator's layer
+boundaries (``repro.sim``, ``repro.launch`` and its ``prepare`` /
+``upload`` / ``device`` / ``readback`` parts, ``repro.host.report``,
+``repro.comm.*``, ``repro.sched.sync``).  They cost about a microsecond
+each and record nothing unless a profiler runs; to see them, run any
+``Workload.run`` under ``jax.profiler.trace(dir)`` and open the trace in
+Perfetto, where they sit on the device operations' clock::
+
+    import jax
+    with jax.profiler.trace("/tmp/prof", create_perfetto_trace=True):
+        wl.get("BFS").run(PIMSystem(cfg), 16, scale=0.1)
 """
 from __future__ import annotations
 

@@ -21,6 +21,7 @@ import numpy as np
 from repro.core.asm import Program, Reg
 from repro.core.config import DPUConfig
 from repro.core.host import PIMSystem
+from repro.obs import spans
 
 BLK = 1024  # streaming DMA block (bytes), PrIM-style staging granularity
 
@@ -60,13 +61,15 @@ class Workload:
         """Public entry point for every workload.  ``pipeline=N`` (N > 1)
         switches to the double-buffered batch mode for any workload;
         subclasses customize execution by overriding :meth:`_run`, never
-        this dispatcher."""
-        if pipeline > 1:
-            st, rep, _ = self.run_pipelined(system, n_threads,
-                                            n_batches=pipeline, scale=scale,
-                                            seed=seed, cache_mode=cache_mode)
-            return st, rep
-        return self._run(system, n_threads, scale, seed, cache_mode)
+        this dispatcher.  Each call is one ``repro.sim`` host span
+        (:mod:`repro.obs.spans`)."""
+        with spans.simulation(self.name, seed):
+            if pipeline > 1:
+                st, rep, _ = self.run_pipelined(
+                    system, n_threads, n_batches=pipeline, scale=scale,
+                    seed=seed, cache_mode=cache_mode)
+                return st, rep
+            return self._run(system, n_threads, scale, seed, cache_mode)
 
     def _run(self, system: PIMSystem, n_threads: int, scale: float = 1.0,
              seed: int = 0, cache_mode: bool = False):
