@@ -41,6 +41,69 @@ from repro.core.isa import Op
 RUN, BLK_DMA, BLK_BAR, DONE = 0, 1, 2, 3
 INF = jnp.int32(1 << 30)
 MAX_DMA_BYTES = 2048  # UPMEM DMA transfer limit
+#: widest per-lane slice (entries per DPU) read or written as a dense
+#: one-hot select.  XLA lowers a scatter with dynamic indices on the TPU
+#: to a serial loop over the updated lanes, at about the same cost
+#: whatever the array's size, while a select over a few hundred entries
+#: per lane is one fused vector op.  Wider slices (WRAM and MRAM words)
+#: keep their gathers and scatters.
+ONEHOT_MAX = 1024
+
+
+# ---------------------------------------------------------------------------
+# Per-lane state access
+# ---------------------------------------------------------------------------
+
+
+def _lane_mask(x, idx):
+    """Mask over axes ``1..len(idx)`` of ``x``, true per DPU lane ``d``
+    at ``idx[0][d], idx[1][d], ...``; size one on any trailing axis."""
+    mask = True
+    for k, i in enumerate(idx):
+        shape = [1] * x.ndim
+        shape[1 + k] = x.shape[1 + k]
+        mask = mask & (jnp.arange(x.shape[1 + k]).reshape(shape)
+                       == i.reshape((-1,) + (1,) * (x.ndim - 1)))
+    return mask
+
+
+def _narrow(x, idx):
+    return int(np.prod(x.shape[1:1 + len(idx)])) <= ONEHOT_MAX
+
+
+def lane_get(x, *idx):
+    """``x[d, idx[0][d], ...]`` for every DPU lane ``d`` of int or bool
+    ``x``: a one-hot pick over a narrow slice, a gather over a wide one.
+    An index out of range reads 0 (False) from a narrow slice."""
+    if not _narrow(x, idx):
+        return x[(jnp.arange(x.shape[0]),) + idx]
+    axes = tuple(range(1, 1 + len(idx)))
+    mask = _lane_mask(x, idx)
+    if x.dtype == jnp.bool_:
+        return (mask & x).any(axes)
+    return jnp.where(mask, x, 0).sum(axes)
+
+
+def lane_set(x, idx, v, when):
+    """``x`` with ``x[d, idx[0][d], ...] = v[d]`` on the lanes where
+    ``when`` holds: a one-hot select over a narrow slice, a scatter into a
+    wide one.  ``idx`` is one index array or a tuple of them; an index out
+    of range writes nothing."""
+    idx = idx if isinstance(idx, tuple) else (idx,)
+    v = jnp.asarray(v)
+    if not _narrow(x, idx):
+        at = (jnp.arange(x.shape[0]),) + idx
+        return x.at[at].set(jnp.where(when, v, x[at]))
+    mask = _lane_mask(x, idx) & when.reshape((-1,) + (1,) * (x.ndim - 1))
+    if v.ndim:
+        v = v.reshape(v.shape[:1] + (1,) * len(idx) + v.shape[1:])
+    return jnp.where(mask, v, x)
+
+
+def lane_add(x, idx, v):
+    """``x`` with ``v[d]`` added at ``x[d, idx[d]]`` on every lane (the
+    counters it updates are all narrow)."""
+    return x + jnp.where(_lane_mask(x, (idx,)), v[:, None], 0)
 
 
 # ---------------------------------------------------------------------------
@@ -206,7 +269,7 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
     tsel = jnp.argmin(jnp.where(ready, prio, INF), axis=-1)
     valid = can
 
-    pcv = st["pc"][dd, tsel]
+    pcv = lane_get(st["pc"], tsel)
     op = iop[pcv]
     rdv = ird[pcv]
     rav = ira[pcv]
@@ -214,34 +277,31 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
     immv = iimm[pcv]
     uiv = iui[pcv] != 0
 
-    a = st["regs"][dd, tsel, rav]
-    breg = st["regs"][dd, tsel, rbv]
+    regs_t = lane_get(st["regs"], tsel)                 # (D, N_REGS)
+    a = lane_get(regs_t, rav)
+    breg = lane_get(regs_t, rbv)
     b = jnp.where(uiv, immv, breg)
 
     # ---- datapath ----
     alu = alu_exec(op, a, b)
     addr = a + immv
     widx = jnp.clip(addr >> 2, 0, st["wram"].shape[1] - 1)
-    ldval = st["wram"][dd, widx]
+    ldval = lane_get(st["wram"], widx)
     special = jnp.stack(
-        [st["regs"][dd, tsel, isa.R_TID], st["regs"][dd, tsel, isa.R_NT],
-         st["regs"][dd, tsel, isa.R_DPU], st["regs"][dd, tsel, isa.R_NDPU]], -1)
-    spc = special[dd, jnp.clip(immv, 0, 3)]
+        [regs_t[:, isa.R_TID], regs_t[:, isa.R_NT],
+         regs_t[:, isa.R_DPU], regs_t[:, isa.R_NDPU]], -1)
+    spc = lane_get(special, jnp.clip(immv, 0, 3))
 
     res = jnp.where(op <= Op.SLTU, alu,
           jnp.where(op == Op.LW, ldval,
           jnp.where(op == Op.JAL, pcv + 1, spc)))
 
     writes_rd = jnp.asarray(isa.WRITES_RD)[op] & valid
-    dst = jnp.where(writes_rd, rdv, 0)
-    cur = st["regs"][dd, tsel, dst]
-    regs = st["regs"].at[dd, tsel, dst].set(jnp.where(writes_rd, res, cur))
+    regs = lane_set(st["regs"], (tsel, rdv), res, writes_rd)
 
     # ---- stores ----
     do_sw = valid & (op == Op.SW)
-    sidx = jnp.where(do_sw, widx, 0)
-    wram = st["wram"].at[dd, sidx].set(
-        jnp.where(do_sw, breg, st["wram"][dd, sidx]))
+    wram = lane_set(st["wram"], widx, breg, do_sw)
 
     # ---- cache-centric mode: LW/SW go through the D$ timing model ----
     status = st["status"]
@@ -255,64 +315,56 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
         line = addr // cfg.line_bytes
         n_sets = dc_tags.shape[1]
         cset = jnp.where(is_mem, line % n_sets, 0)
-        tags_s = dc_tags[dd, cset]                      # (D, ways)
+        tags_s = lane_get(dc_tags, cset)                # (D, ways)
         match = tags_s == line[:, None]
         hit = is_mem & match.any(-1)
         miss = is_mem & ~match.any(-1)
         hitway = jnp.argmax(match, -1)
-        victim = jnp.argmin(dc_lru[dd, cset], -1)
+        victim = jnp.argmin(lane_get(dc_lru, cset), -1)
         way = jnp.where(hit, hitway, victim)
         # dirty-victim writeback folded into the fill size
-        vic_dirty = dc_dirty[dd, cset, victim] & (tags_s[dd, victim] >= 0)
+        vic_dirty = (lane_get(dc_dirty, cset, victim)
+                     & (lane_get(tags_s, victim) >= 0))
         fill_bytes = cfg.line_bytes + jnp.where(vic_dirty, cfg.line_bytes, 0)
         # install on miss (data is functionally in WRAM already)
-        dc_tags = dc_tags.at[dd, cset, way].set(
-            jnp.where(is_mem, line, dc_tags[dd, cset, way]))
-        dc_lru = dc_lru.at[dd, cset, way].set(
-            jnp.where(is_mem, cycle, dc_lru[dd, cset, way]))
+        dc_tags = lane_set(dc_tags, (cset, way), line, is_mem)
+        dc_lru = lane_set(dc_lru, (cset, way), cycle, is_mem)
         new_dirty = jnp.where(miss, op == Op.SW,
-                              dc_dirty[dd, cset, way] | (op == Op.SW))
-        dc_dirty = dc_dirty.at[dd, cset, way].set(
-            jnp.where(is_mem, new_dirty, dc_dirty[dd, cset, way]))
+                              lane_get(dc_dirty, cset, way) | (op == Op.SW))
+        dc_dirty = lane_set(dc_dirty, (cset, way), new_dirty, is_mem)
         # miss blocks the tasklet behind a DRAM fill of the line
-        status = status.at[dd, tsel].set(
-            jnp.where(miss, BLK_DMA, status[dd, tsel]))
-        req_valid = req_valid.at[dd, tsel].set(req_valid[dd, tsel] | miss)
-        req_mram = req_mram.at[dd, tsel].set(
-            jnp.where(miss, line * cfg.line_bytes, req_mram[dd, tsel]))
-        req_bytes = req_bytes.at[dd, tsel].set(
-            jnp.where(miss, fill_bytes, req_bytes[dd, tsel]))
-        req_write = req_write.at[dd, tsel].set(
-            jnp.where(miss, False, req_write[dd, tsel]))
-        req_enq = req_enq.at[dd, tsel].set(
-            jnp.where(miss, cycle, req_enq[dd, tsel]))
+        status = lane_set(status, tsel, BLK_DMA, miss)
+        req_valid = lane_set(req_valid, tsel, True, miss)
+        req_mram = lane_set(req_mram, tsel, line * cfg.line_bytes, miss)
+        req_bytes = lane_set(req_bytes, tsel, fill_bytes, miss)
+        req_write = lane_set(req_write, tsel, False, miss)
+        req_enq = lane_set(req_enq, tsel, cycle, miss)
         c_dc_hit = c_dc_hit + hit.astype(jnp.int32)
         c_dc_miss = c_dc_miss + miss.astype(jnp.int32)
 
     # ---- atomics ----
     mid = jnp.clip(immv, 0, st["atomic"].shape[1] - 1)
-    held = st["atomic"][dd, mid] != 0
+    held = lane_get(st["atomic"], mid) != 0
     acq_ok = valid & (op == Op.ACQUIRE) & ~held
     acq_retry = valid & (op == Op.ACQUIRE) & held
     rel = valid & (op == Op.RELEASE)
-    aval = jnp.where(acq_ok, 1, jnp.where(rel, 0, st["atomic"][dd, mid]))
-    atomic = st["atomic"].at[dd, mid].set(aval)
+    atomic = lane_set(st["atomic"], mid, acq_ok.astype(jnp.int32),
+                      acq_ok | rel)
 
     # ---- DMA ----
     do_dma = valid & ((op == Op.LDMA) | (op == Op.SDMA))
     if cfg.cache_mode:
         do_dma = do_dma & False  # cache-mode programs address memory directly
-    size = jnp.where(uiv, immv, st["regs"][dd, tsel, rdv])
+    size = jnp.where(uiv, immv, lane_get(regs_t, rdv))
     size = jnp.clip(size, 0, MAX_DMA_BYTES)
     is_w = op == Op.SDMA
-    status = status.at[dd, tsel].set(
-        jnp.where(do_dma, BLK_DMA, status[dd, tsel]))
-    req_valid = req_valid.at[dd, tsel].set(req_valid[dd, tsel] | do_dma)
-    req_wram = req_wram.at[dd, tsel].set(jnp.where(do_dma, a, req_wram[dd, tsel]))
-    req_mram = req_mram.at[dd, tsel].set(jnp.where(do_dma, breg, req_mram[dd, tsel]))
-    req_bytes = req_bytes.at[dd, tsel].set(jnp.where(do_dma, size, req_bytes[dd, tsel]))
-    req_write = req_write.at[dd, tsel].set(jnp.where(do_dma, is_w, req_write[dd, tsel]))
-    req_enq = req_enq.at[dd, tsel].set(jnp.where(do_dma, cycle, req_enq[dd, tsel]))
+    status = lane_set(status, tsel, BLK_DMA, do_dma)
+    req_valid = lane_set(req_valid, tsel, True, do_dma)
+    req_wram = lane_set(req_wram, tsel, a, do_dma)
+    req_mram = lane_set(req_mram, tsel, breg, do_dma)
+    req_bytes = lane_set(req_bytes, tsel, size, do_dma)
+    req_write = lane_set(req_write, tsel, is_w, do_dma)
+    req_enq = lane_set(req_enq, tsel, cycle, do_dma)
 
     # functional copy now (timing handled by the DRAM engine); data-race-free
     # programs observe identical results.  Two-tier widths: most DMAs are
@@ -362,31 +414,29 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
             jnp.where((op == Op.JUMP) | (op == Op.JAL), immv,
             jnp.where(op == Op.JR, a,
             jnp.where(acq_retry | (op == Op.STOP), pcv, pcv + 1))))
-    pc = st["pc"].at[dd, tsel].set(jnp.where(valid, new_pc, pcv))
+    pc = lane_set(st["pc"], tsel, new_pc, valid)
 
-    status = status.at[dd, tsel].set(
-        jnp.where(valid & (op == Op.STOP), DONE,
-        jnp.where(valid & (op == Op.BARRIER), BLK_BAR, status[dd, tsel])))
+    status = lane_set(status, tsel, DONE, valid & (op == Op.STOP))
+    status = lane_set(status, tsel, BLK_BAR, valid & (op == Op.BARRIER))
 
     # ---- issue gap: revolver / forwarding / long ops ----
     if cfg.forwarding:
-        ld = st["last_dest"][dd, tsel]
+        ld = lane_get(st["last_dest"], tsel)
         reads_ra = jnp.asarray(isa.READS_RA)[op]
         reads_rb = jnp.asarray(isa.READS_RB)[op] & ~uiv
         raw = (ld >= 0) & ((reads_ra & (rav == ld)) | (reads_rb & (rbv == ld)))
-        nxt = jnp.maximum(cycle + 1, jnp.where(raw, st["last_ready"][dd, tsel], 0))
+        nxt = jnp.maximum(cycle + 1,
+                          jnp.where(raw, lane_get(st["last_ready"], tsel), 0))
     else:
         nxt = cycle + cfg.revolver_cycles
     nxt = nxt + jnp.where(op == Op.MUL, cfg.mul_extra,
                 jnp.where(op == Op.DIV, cfg.div_extra, 0))
-    next_issue = next_issue.at[dd, tsel].set(
-        jnp.where(valid, nxt, next_issue[dd, tsel]))
+    next_issue = lane_set(next_issue, tsel, nxt, valid)
 
-    last_dest = st["last_dest"].at[dd, tsel].set(
-        jnp.where(valid, jnp.where(writes_rd, rdv, -1), st["last_dest"][dd, tsel]))
+    last_dest = lane_set(st["last_dest"], tsel,
+                         jnp.where(writes_rd, rdv, -1), valid)
     ready_at = cycle + jnp.where(op == Op.LW, cfg.wram_load_latency, 1)
-    last_ready = st["last_ready"].at[dd, tsel].set(
-        jnp.where(valid, ready_at, st["last_ready"][dd, tsel]))
+    last_ready = lane_set(st["last_ready"], tsel, ready_at, valid)
 
     # ---- odd/even RF structural hazard ----
     reads_two = (jnp.asarray(isa.READS_RA)[op] & jnp.asarray(isa.READS_RB)[op]
@@ -400,8 +450,7 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
 
     # ---- counters ----
     cls = jnp.asarray(isa.OP_CLASS_TABLE)[op]
-    cls_sel = jnp.where(valid, cls, 0)
-    c_cls = st["c_cls"].at[dd, cls_sel].add(valid.astype(jnp.int32))
+    c_cls = lane_add(st["c_cls"], cls, valid.astype(jnp.int32))
     new_st = dict(st)
     new_st.update(
         regs=regs, wram=wram, mram=mram, atomic=atomic, pc=pc, status=status,
@@ -421,7 +470,8 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
         c_dma_wr_bytes=st["c_dma_wr_bytes"]
         + jnp.where(do_dma & is_w, size, 0).astype(jnp.float32),
     )
-    issued_mask = jnp.zeros_like(st["status"], bool).at[dd, tsel].set(valid)
+    issued_mask = lane_set(jnp.zeros_like(st["status"], bool), tsel, True,
+                           valid)
     return new_st, valid, hazard, issued_mask
 
 
@@ -432,17 +482,13 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
 
 def _dram_step(cfg: DPUConfig, st, cycle):
     D, T = st["status"].shape
-    dd = jnp.arange(D)
 
     # completions
     comp = st["eng_active"] & (st["eng_finish"] <= cycle)
     tf = st["eng_thread"]
-    status = st["status"].at[dd, tf].set(
-        jnp.where(comp, RUN, st["status"][dd, tf]))
-    next_issue = st["next_issue"].at[dd, tf].set(
-        jnp.where(comp, cycle + 1, st["next_issue"][dd, tf]))
-    req_valid = st["req_valid"].at[dd, tf].set(
-        jnp.where(comp, False, st["req_valid"][dd, tf]))
+    status = lane_set(st["status"], tf, RUN, comp)
+    next_issue = lane_set(st["next_issue"], tf, cycle + 1, comp)
+    req_valid = lane_set(st["req_valid"], tf, False, comp)
     eng_active = st["eng_active"] & ~comp
 
     # FR-FCFS selection
@@ -451,11 +497,11 @@ def _dram_step(cfg: DPUConfig, st, cycle):
     hit = row == st["open_row"][:, None]
     score = jnp.where(req_valid, hit.astype(jnp.int32) * INF - st["req_enq"], -INF)
     j = jnp.argmax(score, -1)
-    b_j = st["req_bytes"][dd, j]
-    m_j = st["req_mram"][dd, j]
-    hit_j = hit[dd, j]
+    b_j = lane_get(st["req_bytes"], j)
+    m_j = lane_get(st["req_mram"], j)
+    hit_j = lane_get(hit, j)
     end_row = (m_j + jnp.maximum(b_j, 1) - 1) // cfg.row_bytes
-    extra_rows = end_row - row[dd, j]
+    extra_rows = end_row - lane_get(row, j)
     overhead = jnp.where(hit_j, cfg.row_hit_overhead, cfg.row_miss_overhead)
     overhead = overhead + extra_rows * cfg.row_miss_overhead
     transfer = jnp.ceil(b_j / cfg.effective_mram_bw).astype(jnp.int32)
@@ -469,10 +515,8 @@ def _dram_step(cfg: DPUConfig, st, cycle):
         t_hit = match.any(-1)
         mmu_pen = jnp.where(t_hit, 0, cfg.row_miss_overhead)
         way = jnp.where(t_hit, jnp.argmax(match, -1), jnp.argmin(tlb_lru, -1))
-        tlb_tags = tlb_tags.at[dd, way].set(
-            jnp.where(can, page, tlb_tags[dd, way]))
-        tlb_lru = tlb_lru.at[dd, way].set(
-            jnp.where(can, cycle, tlb_lru[dd, way]))
+        tlb_tags = lane_set(tlb_tags, way, page, can)
+        tlb_lru = lane_set(tlb_lru, way, cycle, can)
         c_tlb_hit = c_tlb_hit + (can & t_hit).astype(jnp.int32)
         c_tlb_miss = c_tlb_miss + (can & ~t_hit).astype(jnp.int32)
 
@@ -499,7 +543,6 @@ def _dram_step(cfg: DPUConfig, st, cycle):
 
 def _classify_and_advance(cfg, st, cycle, running, issued_any, n_ready0):
     D, T = st["status"].shape
-    dd = jnp.arange(D)
     runnable = st["status"] == RUN
     ni = jnp.min(jnp.where(runnable, st["next_issue"], INF), -1)
     df = jnp.where(st["eng_active"], st["eng_finish"], INF)
@@ -525,9 +568,10 @@ def _classify_and_advance(cfg, st, cycle, running, issued_any, n_ready0):
 
     new = dict(st)
     if cfg.collect_detail:
-        hist = st["c_hist"].at[dd, jnp.clip(n_ready0, 0, T)].add(
-            running.astype(jnp.int32))
-        hist = hist.at[:, 0].add(jnp.where(running, delta - 1, 0))
+        hist = lane_add(st["c_hist"], jnp.clip(n_ready0, 0, T),
+                        running.astype(jnp.int32))
+        hist = lane_add(hist, jnp.zeros_like(n_ready0),
+                        jnp.where(running, delta - 1, 0))
 
         # TLP time series
         win = cfg.timeseries_window
@@ -537,8 +581,7 @@ def _classify_and_advance(cfg, st, cycle, running, issued_any, n_ready0):
         w_new = new_cycle // win
         crossed = w_new > w_old
         slot = jnp.clip(w_old, 0, L - 1)
-        ts_buf = st["ts_buf"].at[dd, slot].set(
-            jnp.where(crossed, ts_acc / win, st["ts_buf"][dd, slot]))
+        ts_buf = lane_set(st["ts_buf"], slot, ts_acc / win, crossed)
         ts_acc = jnp.where(crossed, 0.0, ts_acc)
         new.update(c_hist=hist, ts_buf=ts_buf, ts_acc=ts_acc)
 
