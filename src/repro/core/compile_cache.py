@@ -36,10 +36,11 @@ trace.  Each launch is a ``repro.launch`` host span with four children
 Knobs: :data:`PROGRAM_BUCKET_FLOOR` / :data:`DPU_BUCKET_FLOOR` set the
 smallest bucket (smaller floors = tighter shapes but more executables).
 :func:`prewarm` compiles ahead of time; :func:`stats` exposes the
-hit/miss counters the tests assert on, the engine's loop iterations and
-the bytes uploaded per launch.  Entry points (never the library or the
-tests) call :func:`use_persistent_cache` so compiled executables also
-survive the process.
+hit/miss counters the tests assert on, the engine's loop iterations,
+the bytes uploaded per launch and the lane-cycles that padded and
+early-finishing lanes step through.  Entry points (never the library or
+the tests) call :func:`use_persistent_cache` so compiled executables
+also survive the process.
 """
 from __future__ import annotations
 
@@ -104,6 +105,11 @@ def dpu_bucket(n_dpus: int) -> int:
     return pow2_bucket(n_dpus, DPU_BUCKET_FLOOR)
 
 
+def _lanes(cfg: DPUConfig, pad: bool) -> int:
+    """Width of the engine's DPU axis for a launch of ``cfg``."""
+    return dpu_bucket(cfg.n_dpus) if pad else cfg.n_dpus
+
+
 @dataclass
 class _Entry:
     """One cached executable: a jitted binary-agnostic while-loop driver."""
@@ -127,6 +133,8 @@ _HITS = 0
 _MISSES = 0
 _LOOP_ITERS = 0
 _H2D_BYTES = 0
+_LANE_CYCLES = 0
+_DPU_CYCLES = 0
 
 
 def _make_go(cfg: DPUConfig, be: "backends.ExecBackend", T: int) -> Callable:
@@ -204,7 +212,7 @@ def _launch(cfg: DPUConfig, binary, wram_init, mram_init, T: int,
         mram_init = np.ascontiguousarray(np.asarray(mram_init, np.int32))
         capacity = binary.opcode.shape[0]
         P = program_bucket(binary.n_instrs, capacity) if pad else capacity
-        Dp = dpu_bucket(cfg.n_dpus) if pad else cfg.n_dpus
+        Dp = _lanes(cfg, pad)
         st0 = _padded_state(cfg, be, binary, wram_init, mram_init, T, Dp,
                             all_done=all_done, ndpus_reg=ndpus_reg)
         entry, hit = _get_entry(cfg, be, P, Dp, T, mram_init.shape[1])
@@ -229,9 +237,20 @@ def _count_iters(iters) -> None:
         _LOOP_ITERS += n
 
 
-def _launch_span(cfg: DPUConfig, be: "backends.ExecBackend"):
+def _count_cycles(cycles: np.ndarray, lanes: int) -> None:
+    """Count one launch from its real DPUs' final cycles (host numpy,
+    already read back): every lane steps until the slowest DPU is done,
+    and each real DPU is simulated for its own cycles."""
+    global _LANE_CYCLES, _DPU_CYCLES
+    lane, live = lanes * int(cycles.max()), int(cycles.sum())
+    with _LOCK:
+        _LANE_CYCLES += lane
+        _DPU_CYCLES += live
+
+
+def _launch_span(cfg: DPUConfig, be: "backends.ExecBackend", pad: bool):
     return spans.span(spans.LAUNCH, sim_id=spans.sim_id(), backend=be.name,
-                      dpus=cfg.n_dpus)
+                      dpus=cfg.n_dpus, lanes=_lanes(cfg, pad))
 
 
 def run(cfg: DPUConfig, binary, wram_init, mram_init, n_threads: int = None,
@@ -255,7 +274,7 @@ def run(cfg: DPUConfig, binary, wram_init, mram_init, n_threads: int = None,
     logical ``cfg.n_dpus`` rows."""
     be = backends.get(resolve_backend(cfg, backend))
     T = n_threads or cfg.n_tasklets
-    with _launch_span(cfg, be) as launch_span:
+    with _launch_span(cfg, be, pad) as launch_span:
         _, hit, out, iters = _launch(cfg, binary, wram_init, mram_init, T,
                                      be, pad, ndpus_reg=ndpus_reg)
         launch_span.set_metadata(cache="hit" if hit else "miss")
@@ -264,6 +283,7 @@ def run(cfg: DPUConfig, binary, wram_init, mram_init, n_threads: int = None,
             if out["status"].shape[0] != cfg.n_dpus:
                 out = jax.tree_util.tree_map(lambda x: x[:cfg.n_dpus], out)
             _count_iters(iters)
+        _count_cycles(out["cycle"], _lanes(cfg, pad))
     return out
 
 
@@ -281,7 +301,7 @@ def prewarm(cfg: DPUConfig, binary, mram_words: int = None,
     M = mram_words or cfg.mram_words
     wram = np.zeros((cfg.n_dpus, 1), np.int32)
     mram = np.zeros((cfg.n_dpus, M), np.int32)
-    with _launch_span(cfg, be) as launch_span:
+    with _launch_span(cfg, be, True) as launch_span:
         entry, hit, _, iters = _launch(cfg, binary, wram, mram, T, be, True,
                                        all_done=True)
         launch_span.set_metadata(cache="hit" if hit else "miss")
@@ -300,7 +320,13 @@ def stats() -> Dict[str, int]:
     the engine's ``while_loop`` iterations (one may advance several
     simulated cycles under ``event_skip``; an all-``DONE`` prewarm adds
     none), ``h2d_bytes`` the bytes uploaded to the device (state leaves
-    plus instruction image)."""
+    plus instruction image).  ``lane_cycles`` sums, over :func:`run`'s
+    launches, the engine's lane count (the padded DPU bucket) times the
+    launch's slowest-DPU cycles; ``dpu_cycles`` sums each real DPU's
+    own cycles.  ``dpu_cycles / lane_cycles`` is the share of lane-cycles
+    that simulate a live DPU: padded lanes and lanes whose DPU finished
+    early lower it.  Both are counted on the host from the state read
+    back; an all-``DONE`` prewarm adds none."""
     with _LOCK:
         return {
             "entries": len(_ENTRIES),
@@ -309,6 +335,8 @@ def stats() -> Dict[str, int]:
             "launches": sum(e.launches for e in _ENTRIES.values()),
             "loop_iters": _LOOP_ITERS,
             "h2d_bytes": _H2D_BYTES,
+            "lane_cycles": _LANE_CYCLES,
+            "dpu_cycles": _DPU_CYCLES,
         }
 
 
@@ -322,7 +350,8 @@ def cache_info():
 
 def clear():
     """Drop every cached executable and zero the counters (tests)."""
-    global _HITS, _MISSES, _LOOP_ITERS, _H2D_BYTES
+    global _HITS, _MISSES, _LOOP_ITERS, _H2D_BYTES, _LANE_CYCLES, _DPU_CYCLES
     with _LOCK:
         _ENTRIES.clear()
         _HITS = _MISSES = _LOOP_ITERS = _H2D_BYTES = 0
+        _LANE_CYCLES = _DPU_CYCLES = 0

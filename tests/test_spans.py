@@ -96,8 +96,8 @@ def test_profiled_simulation_records_nested_spans(tmp_path):
         launches = [s for s in mine if s[0] == spans.LAUNCH]
         assert len(launches) == 2 * (1 + (sim is sims[1]))
         for la in launches:
-            assert (la[3]["sim_id"], la[3]["backend"], la[3]["dpus"]) == \
-                (sim[3]["sim_id"], "scalar", 2)
+            assert (la[3]["sim_id"], la[3]["backend"], la[3]["dpus"],
+                    la[3]["lanes"]) == (sim[3]["sim_id"], "scalar", 2, 2)
             parts = [s[0] for s in mine if _inside(s, la) and s is not la]
             assert parts == launch_parts
     # the first launch built the executable that every later one reused
@@ -140,6 +140,37 @@ def test_prewarm_counts_no_loop_iterations():
     compile_cache.prewarm(cfg, binary, n_threads=8)
     s = compile_cache.stats()
     assert s["loop_iters"] == 0 and s["h2d_bytes"] > 0
+
+
+def test_prewarm_counts_no_lane_cycles():
+    cfg, binary, _, _ = _va(n_dpus=3)
+    compile_cache.clear()
+    compile_cache.prewarm(cfg, binary, n_threads=8)
+    s = compile_cache.stats()
+    assert (s["lane_cycles"], s["dpu_cycles"]) == (0, 0)
+
+
+@pytest.mark.parametrize("pad,lanes", [(True, 4), (False, 3)])
+def test_lane_cycles_count_every_stepping_lane(pad, lanes):
+    cfg, binary, wram, mram = _va(n_dpus=3)     # bucketed to 4 lanes
+    compile_cache.clear()
+    out = compile_cache.run(cfg, binary, wram, mram, n_threads=8, pad=pad)
+    s = compile_cache.stats()
+    assert s["lane_cycles"] == lanes * int(out["cycle"].max())
+    assert s["dpu_cycles"] == int(out["cycle"].sum())
+    if not pad:     # VA's DPUs finish together: every lane is live
+        assert s["lane_cycles"] == s["dpu_cycles"]
+    compile_cache.run(cfg, binary, wram, mram, n_threads=8, pad=pad)
+    assert compile_cache.stats()["lane_cycles"] == 2 * s["lane_cycles"]
+
+
+@pytest.mark.parametrize("pad,lanes", [(True, 4), (False, 3)])
+def test_launch_span_carries_the_lane_count(tmp_path, pad, lanes):
+    cfg, binary, wram, mram = _va(n_dpus=3)
+    recorded = _profile(tmp_path, lambda: compile_cache.run(
+        cfg, binary, wram, mram, n_threads=8, pad=pad))
+    (launch,) = [s for s in recorded if s[0] == spans.LAUNCH]
+    assert (launch[3]["dpus"], launch[3]["lanes"]) == (3, lanes)
 
 
 @pytest.mark.parametrize("event_skip", [False, True])
