@@ -17,7 +17,10 @@ the host launch path need to run *any* architecture:
   key (two configs with equal keys share one XLA executable);
 * :meth:`~ExecBackend.pad_lanes` — mask DPU-bucket padding rows so they
   never issue;
-* :meth:`~ExecBackend.report` — final state -> :class:`KernelReport`.
+* :meth:`~ExecBackend.report` — final state -> :class:`KernelReport`;
+* :meth:`~ExecBackend.to_carry` / :meth:`~ExecBackend.from_carry` — the
+  state's layout on the device, inside one launch (the identity unless a
+  backend's step wants another).
 
 Backends register by name; :func:`resolve_backend` is the one place the
 default (``cfg.backend``, else SIMT-iff-``simt_width``) is decided.
@@ -76,6 +79,16 @@ class ExecBackend:
         """Aggregate the final state's counters into a KernelReport."""
         return stats.report_from_state(name, cfg, st, n_threads)
 
+    def to_carry(self, st):
+        """The state as the step takes it, from :meth:`make_state`'s
+        layout (applied on the host before the upload)."""
+        return st
+
+    def from_carry(self, st):
+        """Inverse of :meth:`to_carry` (applied on the host after the
+        readback)."""
+        return st
+
     # ---- lane masking (engine-family layout; override if different) --------
     def pad_lanes(self, cfg: DPUConfig, st, logical_d: int) -> None:
         """Mask DPU-bucket padding rows (``logical_d:``) so they never
@@ -104,6 +117,12 @@ class ScalarBackend(ExecBackend):
 
     def step_driver(self, cfg, n_threads):
         return engine.make_step_traced(cfg), engine.make_cond(cfg)
+
+    def to_carry(self, st):
+        return engine.to_carry(st)
+
+    def from_carry(self, st):
+        return engine.from_carry(st)
 
 
 class SimtBackend(ExecBackend):

@@ -203,8 +203,8 @@ def _launch(cfg: DPUConfig, binary, wram_init, mram_init, T: int,
             be: "backends.ExecBackend", pad: bool, all_done: bool = False,
             ndpus_reg: int = None):
     """Build, upload and run one launch.  Returns ``(entry, cache hit,
-    final device state, loop iterations as a device scalar)``, the
-    device work finished."""
+    final device state in the backend's carry form, loop iterations as a
+    device scalar)``, the device work finished."""
     global _H2D_BYTES
     with spans.span(spans.LAUNCH_PREPARE):
         be.validate(cfg, binary, T)
@@ -213,8 +213,9 @@ def _launch(cfg: DPUConfig, binary, wram_init, mram_init, T: int,
         capacity = binary.opcode.shape[0]
         P = program_bucket(binary.n_instrs, capacity) if pad else capacity
         Dp = _lanes(cfg, pad)
-        st0 = _padded_state(cfg, be, binary, wram_init, mram_init, T, Dp,
-                            all_done=all_done, ndpus_reg=ndpus_reg)
+        st0 = be.to_carry(_padded_state(cfg, be, binary, wram_init,
+                                        mram_init, T, Dp, all_done=all_done,
+                                        ndpus_reg=ndpus_reg))
         entry, hit = _get_entry(cfg, be, P, Dp, T, mram_init.shape[1])
         ir = tuple(a[:P] for a in binary.arrays)
     nbytes = _nbytes(st0) + _nbytes(ir)
@@ -279,7 +280,7 @@ def run(cfg: DPUConfig, binary, wram_init, mram_init, n_threads: int = None,
                                      be, pad, ndpus_reg=ndpus_reg)
         launch_span.set_metadata(cache="hit" if hit else "miss")
         with spans.span(spans.LAUNCH_READBACK, nbytes=_nbytes(out)):
-            out = jax.tree_util.tree_map(np.asarray, out)
+            out = be.from_carry(jax.tree_util.tree_map(np.asarray, out))
             if out["status"].shape[0] != cfg.n_dpus:
                 out = jax.tree_util.tree_map(lambda x: x[:cfg.n_dpus], out)
             _count_iters(iters)

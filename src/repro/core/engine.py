@@ -26,7 +26,6 @@ bit-exact against the cycle-by-cycle mode (see tests + EXPERIMENTS.md
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, Tuple
 
 import jax
@@ -45,8 +44,8 @@ MAX_DMA_BYTES = 2048  # UPMEM DMA transfer limit
 #: one-hot select.  XLA lowers a scatter with dynamic indices on the TPU
 #: to a serial loop over the updated lanes, at about the same cost
 #: whatever the array's size, while a select over a few hundred entries
-#: per lane is one fused vector op.  Wider slices (WRAM and MRAM words)
-#: keep their gathers and scatters.
+#: per lane is one fused vector op.  Wider slices (WRAM and MRAM words,
+#: see :func:`to_carry`) keep their gathers and scatters.
 ONEHOT_MAX = 1024
 
 
@@ -248,6 +247,48 @@ def make_state(cfg: DPUConfig, binary: isa.Binary, wram_init, mram_init,
                                    n_threads))
 
 
+#: WRAM words (over all lanes) up to which the scalar step keeps WRAM and
+#: MRAM as ``[D, W]`` / ``[D, M]`` in its loop carry, and above which it
+#: keeps them flat.  On a TPU v5e, XLA scatters one word per lane into a
+#: tiled ``[D, W]`` int32 array in place up to 32 MiB (512 lanes of 64 KB
+#: WRAM), and above that copies the whole array to a linear layout and
+#: back around every WRAM store; a flat carry has that layout throughout.
+#: Below it the 2-D store is the faster one (measured at 64 lanes).
+FLAT_CARRY_WORDS = 1 << 23
+
+
+def _lane_words(x, D, idx):
+    """Index of word ``idx[d]`` (or ``idx[d, k]``) of lane ``d`` into lane
+    memory ``x``, ``[D, W]`` or flat ``[D * W]``."""
+    dd = jnp.arange(D).reshape((D,) + (1,) * (idx.ndim - 1))
+    if x.ndim == 2:
+        return dd, idx
+    return dd * (x.shape[0] // D) + idx
+
+
+def to_carry(st: Dict) -> Dict:
+    """The state as the scalar step's loop carries it: ``st`` itself, or,
+    where WRAM holds more than :data:`FLAT_CARRY_WORDS` words, ``st``
+    with ``wram`` and ``mram`` flattened to ``[D * W]`` and ``[D * M]``
+    (numpy or jax arrays; a C-contiguous numpy reshape is a view)."""
+    if st["wram"].size <= FLAT_CARRY_WORDS:
+        return st
+    D = st["status"].shape[0]
+    words = D * max(st["wram"].shape[1], st["mram"].shape[1])
+    if words >= 1 << 31:
+        raise ValueError(f"{words} words of WRAM or MRAM over {D} DPUs: "
+                         "the flat int32 word index stops at 2**31")
+    return dict(st, wram=st["wram"].reshape(-1), mram=st["mram"].reshape(-1))
+
+
+def from_carry(st: Dict) -> Dict:
+    """Inverse of :func:`to_carry`: ``wram`` and ``mram`` as ``[D, W]``
+    and ``[D, M]``."""
+    D = st["status"].shape[0]
+    return dict(st, wram=st["wram"].reshape(D, -1),
+                mram=st["mram"].reshape(D, -1))
+
+
 # ---------------------------------------------------------------------------
 # One issue slot
 # ---------------------------------------------------------------------------
@@ -255,9 +296,12 @@ def make_state(cfg: DPUConfig, binary: isa.Binary, wram_init, mram_init,
 
 def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
     """Try to issue one instruction per DPU.  Returns (st, issued, hazard,
-    cls_onehot_updates already applied)."""
+    cls_onehot_updates already applied).  ``st`` is in the carry form of
+    :func:`to_carry`: WRAM and MRAM as ``[D, W]`` and ``[D, M]`` or flat,
+    indexed through :func:`_lane_words`."""
     D, T = st["status"].shape
-    dd = jnp.arange(D)
+    W = st["wram"].size // D
+    M = st["mram"].size // D
     iop, ird, ira, irb, iimm, iui = ir
 
     ready = (st["status"] == RUN) & (st["next_issue"] <= cycle[:, None])
@@ -285,8 +329,9 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
     # ---- datapath ----
     alu = alu_exec(op, a, b)
     addr = a + immv
-    widx = jnp.clip(addr >> 2, 0, st["wram"].shape[1] - 1)
-    ldval = lane_get(st["wram"], widx)
+    widx = jnp.clip(addr >> 2, 0, W - 1)
+    wat = _lane_words(st["wram"], D, widx)
+    ldval = st["wram"][wat]
     special = jnp.stack(
         [regs_t[:, isa.R_TID], regs_t[:, isa.R_NT],
          regs_t[:, isa.R_DPU], regs_t[:, isa.R_NDPU]], -1)
@@ -301,7 +346,7 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
 
     # ---- stores ----
     do_sw = valid & (op == Op.SW)
-    wram = lane_set(st["wram"], widx, breg, do_sw)
+    wram = st["wram"].at[wat].set(jnp.where(do_sw, breg, ldval))
 
     # ---- cache-centric mode: LW/SW go through the D$ timing model ----
     status = st["status"]
@@ -378,16 +423,14 @@ def _issue_one(cfg: DPUConfig, ir, st, cycle, running, already, slot_block):
             mbase = (jnp.where(do_dma, breg, 0) >> 2)[:, None] + k[None, :]
             nwords = (jnp.where(do_dma, size, 0) + 3) >> 2
             mask = (k[None, :] < nwords[:, None])
-            wbase = jnp.clip(wbase, 0, wram_.shape[1] - 1)
-            mbase = jnp.clip(mbase, 0, mram_.shape[1] - 1)
-            ddk = dd[:, None]
-            rd_m = mram_[ddk, mbase]
-            rd_w = wram_[ddk, wbase]
+            wat_ = _lane_words(wram_, D, jnp.clip(wbase, 0, W - 1))
+            mat_ = _lane_words(mram_, D, jnp.clip(mbase, 0, M - 1))
+            rd_m = mram_[mat_]
+            rd_w = wram_[wat_]
             ld_mask = mask & ~is_w[:, None] & do_dma[:, None]
             st_mask = mask & is_w[:, None] & do_dma[:, None]
-            wram_ = wram_.at[ddk, wbase].set(jnp.where(ld_mask, rd_m, rd_w))
-            mram_ = mram_.at[ddk, mbase].set(
-                jnp.where(st_mask, rd_w, mram_[ddk, mbase]))
+            wram_ = wram_.at[wat_].set(jnp.where(ld_mask, rd_m, rd_w))
+            mram_ = mram_.at[mat_].set(jnp.where(st_mask, rd_w, rd_m))
             return wram_, mram_
         return do_copy
 
@@ -602,7 +645,8 @@ def make_cond(cfg: DPUConfig):
 
 
 def make_step_traced(cfg: DPUConfig):
-    """One simulated cycle as a pure function ``(ir, state) -> state``.
+    """One simulated cycle as a pure function ``(ir, state) -> state`` on
+    the carry form of the state (:func:`to_carry`).
 
     ``ir`` is the instruction image (the 6 SoA int32 vectors of
     :class:`isa.Binary`) passed as *traced operands*: the compiled XLA
@@ -650,11 +694,17 @@ def make_step_traced(cfg: DPUConfig):
 
 def make_step(cfg: DPUConfig, binary: isa.Binary):
     """Back-compat closure form: the instruction image is baked into the
-    step as XLA constants.  Prefer :func:`run` (which goes through the
-    compiled-engine cache) or :func:`make_step_traced`."""
+    step as XLA constants, and the step takes and returns the state of
+    :func:`make_state` (``[D, W]`` WRAM, ``[D, M]`` MRAM).  Prefer
+    :func:`run` (which goes through the compiled-engine cache) or
+    :func:`make_step_traced`."""
     ir = tuple(jnp.asarray(x) for x in binary.arrays)
-    step = make_step_traced(cfg)
-    return functools.partial(step, ir), make_cond(cfg)
+    traced = make_step_traced(cfg)
+
+    def step(st):
+        return from_carry(traced(ir, to_carry(st)))
+
+    return step, make_cond(cfg)
 
 
 def run(cfg: DPUConfig, binary: isa.Binary, wram_init, mram_init,

@@ -7,6 +7,7 @@ results or speed.  The topology is described inside a fixture, never at
 import, so test collection stays identical across pytest-xdist workers.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,13 +70,47 @@ def _compile_engine(cfg, binary, mram_words, n_threads, sharding):
                         np.zeros((1, mram_words), np.int32), n_threads)
     assert all(x.shape[0] == 1 for x in jax.tree_util.tree_leaves(one))
     st = jax.tree_util.tree_map(
-        lambda x: _spec((Dp,) + x.shape[1:], x.dtype, sharding), one)
+        lambda x: jax.ShapeDtypeStruct((Dp,) + x.shape[1:], x.dtype), one)
+    # the device takes the state in the backend's carry form
+    st = jax.tree_util.tree_map(lambda x: _spec(x.shape, x.dtype, sharding),
+                                jax.eval_shape(be.to_carry, st))
     ir = tuple(_spec((P,), a.dtype, sharding) for a in binary.arrays)
     go = compile_cache._make_go(cfg, be, n_threads)
     compiled = go.lower(ir, st).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
     return compiled
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+_RELAYOUT = re.compile(r"= \w+\[([\d,]*)\]\S* (?:copy|reshape|transpose)\(")
+
+
+def _loop_relayouts(hlo: str, sizes) -> list:
+    """Copies, reshapes and transposes of ``sizes`` elements in the
+    compiled HLO's ``while`` bodies and conditions and every computation
+    they call (conditional branches, fusions), as HLO lines."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    todo = re.findall(r"(?:body|condition)=%([\w.\-]+)", hlo)
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        todo += [r for line in comps[c]
+                 for r in re.findall(r"%([\w.\-]+)", line) if r in comps]
+    return [line.strip() for c in seen for line in comps[c]
+            for m in [_RELAYOUT.search(line)]
+            if m and int(np.prod([int(n) for n in m.group(1).split(",")
+                                  if n])) in sizes]
 
 
 def _va(cfg, n_threads=16):
@@ -106,11 +141,17 @@ def test_hbmpim_cmd_gemvs_compiles(one_chip):
 
 
 def test_engine_compiles_full_server(one_chip):
-    """2,560 DPUs (40 ranks x 64) pad to the 4,096-lane bucket."""
+    """2,560 DPUs (40 ranks x 64) pad to the 4,096-lane bucket.  Past
+    ``engine.FLAT_CARRY_WORDS`` the engine keeps WRAM and MRAM flat
+    through its loop: no WRAM- or MRAM-sized relayout in it."""
     cfg = DPUConfig(n_dpus=2560, n_ranks=40, n_tasklets=16,
                     mram_bytes=1 << 16)
     assert compile_cache.dpu_bucket(cfg.n_dpus) == 4096
-    _compile_engine(cfg, _va(cfg), cfg.mram_words, 16, one_chip)
+    hlo = _compile_engine(cfg, _va(cfg), cfg.mram_words, 16,
+                          one_chip).as_text()
+    assert re.search(r"body=%", hlo)
+    assert _loop_relayouts(hlo, {4096 * cfg.wram_words,
+                                 4096 * cfg.mram_words}) == []
 
 
 def test_alu_exec_kernel_compiles(one_chip):

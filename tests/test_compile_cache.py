@@ -1,8 +1,10 @@
 """Compiled-engine cache: executable reuse, shape bucketing, bit-exactness."""
+import jax
 import numpy as np
 import pytest
 
 import repro.workloads as wl
+from repro.core import backend as backends
 from repro.core import compile_cache, engine
 from repro.core.asm import Program
 from repro.core.config import DPUConfig
@@ -122,6 +124,79 @@ def test_padded_bit_exact_simt():
     exact = compile_cache.run(cfg, binary, wram, mram, 8, pad=False)
     for k in exact:
         assert np.array_equal(padded[k], exact[k]), k
+
+
+def test_flat_carry_launch_returns_lane_memories(monkeypatch):
+    """With WRAM and MRAM flat on the device (``FLAT_CARRY_WORDS`` cut to
+    0 here: the real threshold needs more than 512 lanes), a padded
+    launch (5 DPUs on 8 lanes) returns ``[5, W]`` / ``[5, M]``, word for
+    word the unpadded ``[D, W]`` run's, in every state leaf."""
+    cfg, binary, wram, mram, _ = _setup("VA", n_dpus=5)
+    lanes = compile_cache.run(cfg, binary, wram, mram, 8, pad=False)
+    monkeypatch.setattr(engine, "FLAT_CARRY_WORDS", 0)
+    flat = compile_cache.run(cfg, binary, wram, mram, 8, pad=True)
+    compile_cache.clear()
+    for k, words in (("wram", cfg.wram_words), ("mram", mram.shape[1])):
+        assert flat[k].shape == (5, words), k
+    for k in lanes:
+        assert np.array_equal(flat[k], lanes[k]), k
+
+
+@pytest.mark.parametrize("backend,flat", [("scalar", True), ("simt", False)])
+def test_driver_takes_the_backends_carry_form(monkeypatch, backend, flat):
+    """``ExecBackend.to_carry`` shapes what the jitted driver is given:
+    flat WRAM and MRAM for the scalar engine past ``FLAT_CARRY_WORDS``
+    (cut to 0 here), the identity for simt."""
+    monkeypatch.setattr(engine, "FLAT_CARRY_WORDS", 0)
+    kw = {"simt_width": 4} if backend == "simt" else {}
+    cfg, binary, wram, mram, _ = _setup("VA", n_dpus=3, **kw)
+    seen = {}
+    make_go = compile_cache._make_go
+
+    def spy(cfg, be, T):
+        go = make_go(cfg, be, T)
+
+        def call(ir, st):
+            seen.update(wram=st["wram"].shape, mram=st["mram"].shape)
+            return go(ir, st)
+        return call
+
+    monkeypatch.setattr(compile_cache, "_make_go", spy)
+    compile_cache.clear()
+    out = compile_cache.run(cfg, binary, wram, mram, 8, backend=backend)
+    compile_cache.clear()
+    Dp = compile_cache.dpu_bucket(3)
+    lanes = {"wram": (Dp, cfg.wram_words), "mram": (Dp, mram.shape[1])}
+    for k, shape in lanes.items():
+        assert seen[k] == ((Dp * shape[1],) if flat else shape), k
+        assert out[k].shape == (3, shape[1]), k
+
+
+def test_scalar_carry_is_flat_past_the_threshold():
+    """One rank keeps ``[D, W]`` lane memories; the 4,096-lane server's
+    are flat (shapes only: nothing is allocated)."""
+    be = backends.get("scalar")
+    for lanes, flat in ((64, False), (512, False), (1024, True),
+                        (4096, True)):
+        st = {"status": jax.ShapeDtypeStruct((lanes, 16), np.int32),
+              "wram": jax.ShapeDtypeStruct((lanes, 1 << 14), np.int32),
+              "mram": jax.ShapeDtypeStruct((lanes, 1 << 16), np.int32)}
+        carry = jax.eval_shape(be.to_carry, st)
+        assert carry["wram"].ndim == carry["mram"].ndim == 2 - flat, lanes
+        back = jax.eval_shape(be.from_carry, carry)
+        assert {k: x.shape for k, x in back.items()} == \
+            {k: x.shape for k, x in st.items()}
+
+
+def test_flat_carry_refuses_an_index_past_int32():
+    """2**15 DPUs of 256 KiB MRAM: 2**31 words, one past the flat index
+    (zero-stride views, so nothing is allocated)."""
+    D = 1 << 15
+    st = {"status": np.zeros((D, 16), np.int32),
+          "wram": np.broadcast_to(np.int32(0), (D, 1 << 14)),
+          "mram": np.broadcast_to(np.int32(0), (D, 1 << 16))}
+    with pytest.raises(ValueError, match="2\\*\\*31"):
+        engine.to_carry(st)
 
 
 def test_padded_lanes_see_logical_system_size():

@@ -112,6 +112,15 @@ def test_final_state_digests(case):
     assert digest_case(case) == want
 
 
+@pytest.mark.parametrize("case", CASES)
+def test_final_state_digests_flat_carry(case, monkeypatch):
+    """The same pins with WRAM and MRAM flat in the loop carry, as past
+    ``engine.FLAT_CARRY_WORDS`` (cut to 0 here: 64 lanes hold less)."""
+    monkeypatch.setattr(engine, "FLAT_CARRY_WORDS", 0)
+    want = json.loads(PINS.read_text())[case]
+    assert digest_case(case) == want
+
+
 if __name__ == "__main__" and sys.argv[1:] == ["--write"]:
     PINS.write_text(json.dumps({c: digest_case(c) for c in CASES},
                                indent=1, sort_keys=True) + "\n")

@@ -205,7 +205,7 @@ def test_engine_executable_is_named_after_its_backend(backend):
     kw = {"simt_width": 4} if backend == "simt" else {}
     cfg, binary, wram, mram = _va(n_dpus=2, **kw)
     be = backends.get(backend)
-    st = be.make_state(cfg, binary, wram, mram, 8)
+    st = be.to_carry(be.make_state(cfg, binary, wram, mram, 8))
     P = compile_cache.program_bucket(binary.n_instrs, binary.opcode.shape[0])
     ir = tuple(a[:P] for a in binary.arrays)
     go = compile_cache._make_go(cfg, be, 8)
