@@ -38,6 +38,11 @@ VARIANTS = {
 # DMA and runs the cacheable kernels.
 KERNELS = {"VA": 0.03, "BFS": 0.05, "SYNC": None}
 CACHE_KERNELS = {"VA": 0.03, "BS": 0.05, "SYNC": None}
+# The rest of PrIM, pinned by the ``test_prim_digests_*`` files against
+# ``data/prim_digests.json``.
+PRIM_KERNELS = {name: 0.03 for name in (
+    "RED", "SCAN-SSA", "SCAN-RSS", "SEL", "UNI", "HST-S", "HST-L", "BS",
+    "TS", "GEMV", "TRNS", "SpMV", "NW")}
 
 CASES = [f"{k}/{v}" for v in VARIANTS
          for k in (CACHE_KERNELS if v == "cache_mode" else KERNELS)]
@@ -76,13 +81,14 @@ def run_sync(cfg: DPUConfig):
                np.zeros((cfg.n_dpus, 1), np.int32), mram)
 
 
-def digest_case(case: str) -> dict:
+def digest_case(case: str, n_dpus: int = 64) -> dict:
     """Per-leaf sha256 prefix over the final state of every launch of
-    ``case`` (``"<kernel>/<variant>"``), 64 DPUs, 16 tasklets, seed 0."""
+    ``case`` (``"<kernel>/<variant>"``), ``n_dpus`` DPUs, 16 tasklets,
+    seed 0."""
     name, variant = case.split("/")
     kw = VARIANTS[variant]
     cache_mode = kw.get("cache_mode", False)
-    cfg = DPUConfig(n_dpus=64, n_tasklets=16, mram_bytes=1 << 16, **kw)
+    cfg = DPUConfig(n_dpus=n_dpus, n_tasklets=16, mram_bytes=1 << 16, **kw)
     hashes = {}
     real_run = compile_cache.run
 
@@ -98,9 +104,10 @@ def digest_case(case: str) -> dict:
         if name == "SYNC":
             run_sync(cfg)
         else:
+            scales = CACHE_KERNELS if cache_mode else {**PRIM_KERNELS,
+                                                       **KERNELS}
             wl.get(name).run(PIMSystem(cfg), 16, seed=0, cache_mode=cache_mode,
-                             scale=(CACHE_KERNELS if cache_mode
-                                    else KERNELS)[name])
+                             scale=scales[name])
     finally:
         compile_cache.run = real_run
     return {leaf: h.hexdigest()[:16] for leaf, h in sorted(hashes.items())}
