@@ -1,16 +1,12 @@
-"""Render reports/dryrun/*.json into the EXPERIMENTS.md markdown tables,
-and per-kernel counter rows (``KernelReport.to_row()`` dicts or a
+"""Render per-kernel counter rows (``KernelReport.to_row()`` dicts or a
 ``RunProfile`` JSON's ``kernels`` section) into a markdown table with a
 deterministic column order:
 
-    PYTHONPATH=src python benchmarks/make_tables.py [dryrun_dir] \\
-        [--kernels rows.json]
+    PYTHONPATH=src python benchmarks/make_tables.py --kernels rows.json
 """
 from __future__ import annotations
 
-import glob
 import json
-import os
 import sys
 
 #: fixed leading columns of the kernel table; every remaining key is
@@ -19,14 +15,6 @@ KERNEL_COLUMNS = ("name", "launches", "n_dpus", "n_threads", "cycles",
                   "issued", "ipc", "mram_rd_util", "mram_wr_util",
                   "avg_issuable", "acq_retry", "frac_active",
                   "frac_idle_memory", "frac_idle_revolver", "frac_idle_rf")
-
-
-def load(dryrun_dir):
-    rows = []
-    for p in sorted(glob.glob(os.path.join(dryrun_dir, "*.json"))):
-        with open(p) as f:
-            rows.append(json.load(f))
-    return rows
 
 
 def kernel_table(rows):
@@ -54,48 +42,9 @@ def load_kernel_rows(path):
     return data["kernels"] if isinstance(data, dict) else data
 
 
-def dryrun_table(rows, mesh_filter=None):
-    out = ["| arch | shape | mesh | status | args GiB/dev | temp GiB/dev |",
-           "|---|---|---|---|---|---|"]
-    for r in rows:
-        if mesh_filter and r.get("mesh") != mesh_filter:
-            continue
-        b = r.get("bytes_per_device") or {}
-        gib = lambda k: (f"{b.get(k, 0) / 2**30:.2f}" if b else "-")
-        out.append(f"| {r['arch']} | {r['shape']} | {r.get('mesh','-')} | "
-                   f"{r['status']} | {gib('args')} | {gib('temp')} |")
-    return "\n".join(out)
-
-
-def roofline_table(rows):
-    out = ["| arch | shape | kind | compute ms | memory ms | coll ms | "
-           "bottleneck | useful | roofline frac |",
-           "|---|---|---|---|---|---|---|---|---|"]
-    for r in rows:
-        if r.get("mesh") != "16x16" or r.get("status") != "OK":
-            continue
-        out.append(
-            f"| {r['arch']} | {r['shape']} | {r.get('kind','-')} | "
-            f"{r.get('compute_ms')} | {r.get('memory_ms')} | "
-            f"{r.get('collective_ms')} | {r.get('bottleneck')} | "
-            f"{r.get('useful_ratio')} | {r.get('roofline_fraction')} |")
-    return "\n".join(out)
-
-
 if __name__ == "__main__":
-    argv = list(sys.argv[1:])
-    if "--kernels" in argv:
-        i = argv.index("--kernels")
-        kpath = argv[i + 1]
-        del argv[i:i + 2]
-        print("### kernel counters\n")
-        print(kernel_table(load_kernel_rows(kpath)))
-        print()
-    d = argv[0] if argv else "reports/dryrun"
-    rows = load(d)
-    print("### single-pod roofline\n")
-    print(roofline_table(rows))
-    print("\n### dry-run (multi-pod 2x16x16)\n")
-    print(dryrun_table(rows, "2x16x16"))
-    print("\n### dry-run (single-pod 16x16)\n")
-    print(dryrun_table(rows, "16x16"))
+    argv = sys.argv[1:]
+    if len(argv) != 2 or argv[0] != "--kernels":
+        raise SystemExit("usage: make_tables.py --kernels rows.json")
+    print("### kernel counters\n")
+    print(kernel_table(load_kernel_rows(argv[1])))

@@ -4,10 +4,10 @@ Prints ``name,us_per_call,derived`` CSV rows (one per figure/design point).
 ``--scale`` grows datasets toward the paper's Table II sizes; default runs
 the suite at CI scale in a few minutes.  ``--suite`` selects a family
 (``figs`` paper figures, ``comm`` interconnect/collectives, ``overlap``
-async-pipeline, ``lm`` serving roofline, ``faults`` fault-injection
-availability/goodput, ``cluster`` multi-tenant cluster runtime,
-``all``); ``--only`` further filters by substring — a filter matching
-nothing is an error listing the valid bench names, not a silent no-op.
+async-pipeline, ``faults`` fault-injection availability/goodput,
+``cluster`` multi-tenant cluster runtime, ``all``); ``--only`` further
+filters by substring — a filter matching nothing is an error listing
+the valid bench names, not a silent no-op.
 A bench that raises prints an ``error`` row, and the run exits non-zero
 once every selected bench has run.
 
@@ -32,8 +32,8 @@ import os
 import time
 
 #: suite families selectable via --suite (benches declare theirs inline)
-SUITE_NAMES = ("figs", "comm", "overlap", "lm", "faults", "cluster",
-               "overload", "pathfind")
+SUITE_NAMES = ("figs", "comm", "overlap", "faults", "cluster", "overload",
+               "pathfind")
 
 
 def _emit(name: str, wall_s: float, rows):
@@ -50,7 +50,6 @@ def main() -> None:
     ap.add_argument("--list", action="store_true",
                     help="print every registered bench (grouped by suite) "
                          "and exit without running anything")
-    ap.add_argument("--dryrun-dir", default="reports/dryrun")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a Chrome-trace JSON of the run to PATH "
                          "(plus a RunProfile counters snapshot next to it)")
@@ -73,8 +72,8 @@ def main() -> None:
         profile = obs.RunProfile(name=f"bench:{args.suite}")
 
     from benchmarks import cluster_load, comm_scaling, fault_tolerance, \
-        lm_roofline, overlap_scaling, overload, pathfind_arch, pim_figs, \
-        rank_overlap, trace_replay
+        overlap_scaling, overload, pathfind_arch, pim_figs, rank_overlap, \
+        trace_replay
 
     char = None
 
@@ -108,7 +107,6 @@ def main() -> None:
         "fig15_cache": ("figs", lambda: pim_figs.fig15_cache_vs_scratchpad(args.scale), ()),
         "mmu_overhead": ("figs", lambda: pim_figs.mmu_overhead(args.scale), ()),
         "simulation_rate": ("figs", lambda: pim_figs.simulation_rate(args.scale), ()),
-        "lm_roofline": ("lm", lambda: lm_roofline.table(args.dryrun_dir), ()),
         "fault_smoke": ("faults", lambda: [fault_tolerance.smoke()],
                         ("--smoke", "--check")),
         "fault_tolerance": ("faults", lambda: fault_tolerance.sweep(

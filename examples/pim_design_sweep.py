@@ -4,7 +4,7 @@ The paper sweeps one design point at a time on one machine; here the
 (design x workload) grid is over-decomposed into work units and scheduled
 onto a simulated worker fleet with the straggler-aware
 :class:`WorkRebalancer` — the same structure a 1000-chip sweep uses, with
-each TPU chip simulating a slice of the grid (DESIGN.md §3).
+each TPU chip simulating a slice of the grid.
 
     PYTHONPATH=src python examples/pim_design_sweep.py
 """

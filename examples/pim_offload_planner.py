@@ -1,8 +1,8 @@
 """PIM offload planner: should a memory-bound LM op run on PIM?
 
 Reproduces the paper's motivating scenario (the Facebook quote on
-embedding-dominated inference): for GEMV/embedding-gather shapes from the
-assigned LM architectures, compare
+embedding-dominated inference): for GEMV/embedding-gather shapes of
+published LM widths, compare
   * simulated UPMEM-PIM latency (cycle-level, our engine) against
   * a TPU-v5e roofline estimate (bytes / 819 GB/s HBM),
 and emit an offload decision per op.
@@ -19,7 +19,11 @@ import numpy as np
 import repro.workloads as wl
 from repro.core.config import DPUConfig
 from repro.core.host import PIMSystem
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+
+# TPU v5e per-chip peaks, from Google Cloud's published v5e system
+# specifications (https://cloud.google.com/tpu/docs/v5e)
+PEAK_FLOPS = 197e12       # bf16 FLOP/s
+HBM_BW = 819e9            # HBM bytes/s
 
 
 def tpu_time(bytes_moved, flops):

@@ -1,12 +1,11 @@
-"""Admission control primitives: typed backpressure + token buckets.
+"""Admission control primitives: bounded queues + token buckets.
 
 Overloaded queues fail slow — latency grows without bound while every
 queued job's SLO silently expires.  The admission layer fails *fast*
 instead: a bounded queue and per-tenant token-bucket rate limits turn
-excess offered load into a typed :class:`AdmissionRejected` (cluster
-jobs become ``status="rejected"`` outcomes; :meth:`ServeEngine.submit`
-raises) carrying a ``retry_after`` hint — the backpressure signal a
-client needs to shed or retry intelligently.
+excess offered load into cluster jobs with ``status="rejected"``
+outcomes, and a token bucket's :meth:`~TokenBucket.retry_after` is the
+hint a client needs to shed or retry intelligently.
 
 Everything here is deterministic on the caller's clock: a
 :class:`TokenBucket` refills as a pure function of the timestamps it is
@@ -18,33 +17,12 @@ from dataclasses import dataclass, field
 from typing import Dict, Mapping, Optional, Tuple
 
 
-class AdmissionRejected(RuntimeError):
-    """Typed backpressure: the system refused new work *now*.
-
-    ``reason`` is machine-readable (``queue_full`` / ``rate_limited`` /
-    ``capacity``); ``retry_after`` — when known — is the modeled seconds
-    until a retry could succeed (token-bucket refill time)."""
-
-    def __init__(self, tenant: str, reason: str, detail: str = "",
-                 retry_after: Optional[float] = None):
-        self.tenant = tenant
-        self.reason = reason
-        self.detail = detail
-        self.retry_after = retry_after
-        msg = f"{tenant}: {reason}"
-        if detail:
-            msg += f" ({detail})"
-        if retry_after is not None:
-            msg += f"; retry after {retry_after:.6g}s"
-        super().__init__(msg)
-
-
 class TokenBucket:
     """Deterministic token bucket on an external clock.
 
     Holds up to ``burst`` tokens, refilling at ``rate_hz``; one
     admission takes one token.  The caller supplies the timestamps
-    (cluster event clock, serve tick count), so refill is a pure
+    (the cluster event clock), so refill is a pure
     function of the query times — no wall clock anywhere."""
 
     def __init__(self, rate_hz: float, burst: float):

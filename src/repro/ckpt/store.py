@@ -1,4 +1,4 @@
-"""Sharded, manifest-atomic checkpoints with elastic restore.
+"""Sharded, manifest-atomic checkpoints of pytrees.
 
 Layout:
     <dir>/step_<N>.tmp/            (written first)
@@ -7,9 +7,9 @@ Layout:
     <dir>/step_<N>/                (atomic rename commits)
     <dir>/LATEST                   (text file, updated last)
 
-Restore accepts a *different* mesh/shardings than the save used: leaves are
-loaded on host and ``jax.device_put`` against the new sharding — elastic
-re-scale (tested 4-device -> 2-device -> 1-device)."""
+Leaves are saved and restored as host arrays.  ``repro.faults.remap``
+checkpoints a launch's inputs here so that lost shards re-execute from
+storage."""
 from __future__ import annotations
 
 import json
@@ -72,11 +72,9 @@ def latest_step(ckpt_dir: str) -> Optional[int]:
         return int(f.read().strip())
 
 
-def restore(ckpt_dir: str, like, step: Optional[int] = None,
-            shardings=None):
+def restore(ckpt_dir: str, like, step: Optional[int] = None):
     """Restore into the structure of ``like`` (a pytree of arrays or
-    ShapeDtypeStructs).  ``shardings``: optional matching pytree of
-    NamedShardings for elastic placement."""
+    ShapeDtypeStructs)."""
     if step is None:
         step = latest_step(ckpt_dir)
         if step is None:
@@ -92,9 +90,6 @@ def restore(ckpt_dir: str, like, step: Optional[int] = None,
 
     flat_like, treedef = _flatten(like)
     leaves = []
-    shard_flat = None
-    if shardings is not None:
-        shard_flat, _ = _flatten(shardings)
     for key, leaf in flat_like.items():
         if key not in data:
             raise KeyError(f"checkpoint missing leaf {key}")
@@ -104,10 +99,7 @@ def restore(ckpt_dir: str, like, step: Optional[int] = None,
             raise ValueError(f"{key}: shape {arr.shape} != {want_shape}")
         if np.ndim(leaf) == 0 and not hasattr(leaf, "dtype"):
             arr = arr.item()  # python scalar leaf (e.g. iterator step)
-        elif shard_flat is not None:
-            arr = jax.device_put(arr, shard_flat[key])
         leaves.append(arr)
-    keys = list(flat_like.keys())
     # rebuild via unflatten on the like treedef (order matches flatten)
     _, td = jax.tree_util.tree_flatten(like)
     return jax.tree_util.tree_unflatten(td, leaves), step

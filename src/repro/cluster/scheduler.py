@@ -283,7 +283,7 @@ class _Run:
 class ClusterLease:
     """An open-ended rank reservation for a serving tenant: the cluster
     places it like a job and hands back a :class:`PimDecodePool` bound
-    to the ranks (see ``examples/serve_lm.py --cluster``)."""
+    to the ranks (:meth:`PimCluster.lease`)."""
 
     tenant: str
     ranks: Tuple[int, ...]

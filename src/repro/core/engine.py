@@ -1,7 +1,7 @@
 """Vectorized cycle-level DPU engine.
 
-The paper's event/loop C++ simulator is re-thought for TPU execution
-(DESIGN.md §2): *all* microarchitectural state is a pytree of int32 arrays
+The paper's event/loop C++ simulator is re-thought for TPU execution:
+*all* microarchitectural state is a pytree of int32 arrays
 with a leading DPU axis; one simulated cycle is a pure function
 ``SimState -> SimState`` driven by ``jax.lax.while_loop``; every DPU in the
 system advances in the same vectorized step (lane-per-DPU).
@@ -21,8 +21,8 @@ Case-study features are config flags: forwarding (D), unified RF (R),
 Beyond-paper: ``event_skip`` fast-forwards idle gaps to the next event
 (issue-eligibility or DMA completion) while attributing every skipped
 cycle to the paper's idle taxonomy — a pure-performance change validated
-bit-exact against the cycle-by-cycle mode (see tests + EXPERIMENTS.md
-§Perf).
+bit-exact against the cycle-by-cycle mode
+(``tests/test_engine.py::test_event_skip_equivalence``).
 """
 from __future__ import annotations
 
@@ -106,7 +106,7 @@ def lane_add(x, idx, v):
 
 
 # ---------------------------------------------------------------------------
-# ALU datapath (pure-jnp reference; the Pallas kernel mirrors this)
+# ALU datapath (pure jnp)
 # ---------------------------------------------------------------------------
 
 
@@ -690,21 +690,6 @@ def make_step_traced(cfg: DPUConfig):
         return st
 
     return step
-
-
-def make_step(cfg: DPUConfig, binary: isa.Binary):
-    """Back-compat closure form: the instruction image is baked into the
-    step as XLA constants, and the step takes and returns the state of
-    :func:`make_state` (``[D, W]`` WRAM, ``[D, M]`` MRAM).  Prefer
-    :func:`run` (which goes through the compiled-engine cache) or
-    :func:`make_step_traced`."""
-    ir = tuple(jnp.asarray(x) for x in binary.arrays)
-    traced = make_step_traced(cfg)
-
-    def step(st):
-        return from_carry(traced(ir, to_carry(st)))
-
-    return step, make_cond(cfg)
 
 
 def run(cfg: DPUConfig, binary: isa.Binary, wram_init, mram_init,

@@ -12,8 +12,6 @@ lanes touch different rows.  MRAM streaming bandwidth is shared either way
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -303,12 +301,6 @@ def make_step_traced(cfg: DPUConfig):
         return st
 
     return step
-
-
-def make_step(cfg: DPUConfig, binary):
-    """Back-compat closure form (instruction image baked as constants)."""
-    ir = tuple(jnp.asarray(x) for x in binary.arrays)
-    return functools.partial(make_step_traced(cfg), ir), engine.make_cond(cfg)
 
 
 def run(cfg: DPUConfig, binary, wram_init, mram_init, n_threads=None,

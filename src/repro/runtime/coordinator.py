@@ -8,7 +8,7 @@ Three cooperating pieces, each unit-tested with injected faults:
 * :func:`run_with_restarts` — the restart driver: executes a step loop,
   checkpoints every ``ckpt_every`` steps, and on a (detected or raised)
   worker failure restores the latest checkpoint and keeps going, replaying
-  the data pipeline to the restored step.
+  the input iterator to the restored step.
 * :class:`WorkRebalancer` — over-decomposition + greedy re-balancing for
   the PIM design-sweep fleet: work units are re-assigned away from slow
   workers (longest-processing-time heuristic on observed rates).
@@ -53,9 +53,10 @@ def run_with_restarts(step_fn: Callable[[int], Dict], *, state_ref: Dict,
                       data, n_steps: int, ckpt_dir: str, ckpt_every: int = 10,
                       max_failures: int = 10,
                       save_fn=None, restore_fn=None) -> Dict:
-    """Drive ``n_steps`` of training with checkpoint/restart.
+    """Drive ``n_steps`` of a step loop with checkpoint/restart.
 
-    ``step_fn(step)`` advances ``state_ref`` in place (reads ``data``) and
+    ``step_fn(step)`` advances ``state_ref`` in place (reads ``data``, an
+    iterator with ``state_dict``/``load_state_dict``) and
     may raise :class:`WorkerFailure`.  ``save_fn``/``restore_fn`` default to
     npz checkpointing of ``state_ref['state']`` + the data iterator state.
     Returns stats {completed, failures, restores}.

@@ -1,14 +1,15 @@
-"""Simulated PIM decode pool: the serving engine's accelerator lease.
+"""Simulated PIM decode pool: a serving tenant's accelerator lease.
 
-The :class:`ServeEngine` computes tokens on the host either way (the LM
-math is exact); what a PIM pool changes is the *modeled time* of each
-decode tick and — under a fault plan — whether the pool is available at
-all.  :class:`PimDecodePool` charges each tick as a ``modeled_launch``
-on its :class:`~repro.core.host.PIMSystem`, scaled by the surviving-DPU
+The cluster's ``lm_decode`` jobs and its open-ended leases
+(:meth:`repro.cluster.PimCluster.lease`) each hold one.  What the pool
+models is the *time* of each decode tick and — under a fault plan —
+whether the pool is available at all.  :class:`PimDecodePool` charges
+each tick as a ``modeled_launch`` on its
+:class:`~repro.core.host.PIMSystem`, scaled by the surviving-DPU
 fraction (fewer healthy banks means each tick re-runs on a smaller
 slice of the weight-parallel layout), and surfaces pool exhaustion or
-retry-exhausted launches as :class:`DpuFaultError` so the engine can
-fall back to host execution instead of crashing mid-stream."""
+retry-exhausted launches as :class:`DpuFaultError` so the cluster can
+reschedule the job instead of crashing mid-stream."""
 from __future__ import annotations
 
 from typing import Optional, Sequence
@@ -57,13 +58,12 @@ class PimDecodePool:
             healthy = int(mask[lanes].sum())
         return healthy / total if total else 0.0
 
-    def tick(self, n_active: int = 1) -> float:
+    def tick(self) -> float:
         """Charge one pool-wide decode tick; returns the modeled seconds.
 
         Raises :class:`DpuFaultError` when the pool has degraded below
         ``min_fraction`` (or the underlying launch exhausts its
-        retries) — the caller is expected to catch it and decode on the
-        host instead."""
+        retries) — the caller is expected to catch it and reschedule."""
         frac = self.healthy_fraction
         if frac < self.min_fraction:
             if getattr(self.system, "tracer", None) is not None:
@@ -81,16 +81,3 @@ class PimDecodePool:
         self.system.modeled_launch("decode", seconds, ranks=self.ranks)
         self.ticks += 1
         return seconds
-
-    def estimate(self, ticks: int = 1) -> float:
-        """Modeled seconds ``ticks`` decode steps would cost at the
-        pool's *current* health — no charge, no fault draw.  Returns
-        ``inf`` below the availability floor (the pool would refuse to
-        serve).  The serve engine's deadline shedding budgets with
-        this."""
-        if ticks < 0:
-            raise ValueError("ticks must be non-negative")
-        frac = self.healthy_fraction
-        if frac < self.min_fraction:
-            return float("inf")
-        return ticks * self.tick_seconds / frac

@@ -1,5 +1,5 @@
-"""Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests and benches see
-one device; multi-device tests spawn subprocesses with their own flags."""
+"""Shared fixtures.  Tests see one device; nothing here sets device
+flags."""
 import os
 import sys
 

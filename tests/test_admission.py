@@ -3,9 +3,9 @@ launches, circuit breakers, and crash-consistent journal resume."""
 import numpy as np
 import pytest
 
-from repro.admission import (AdmissionPolicy, AdmissionRejected,
-                             CircuitBreaker, ClusterJournal, HedgePolicy,
-                             RankBreakers, SimulatedCrash, TokenBucket)
+from repro.admission import (AdmissionPolicy, CircuitBreaker,
+                             ClusterJournal, HedgePolicy, RankBreakers,
+                             SimulatedCrash, TokenBucket)
 from repro.cluster import (COMPLETED, REJECTED, SHED, JobSpec, PimCluster,
                            TenantSpec, poisson_stream, scale_rates,
                            trace_profile)
@@ -159,48 +159,6 @@ def test_scale_rates():
     assert tenants[0].rate_hz == 100.0         # originals untouched
     with pytest.raises(ValueError):
         scale_rates(tenants, 0.0)
-
-
-@pytest.fixture(scope="module")
-def serve_engine_factory():
-    import jax
-    from repro.configs.base import get_smoke_config
-    from repro.models import transformer as T
-    from repro.serve.engine import ServeEngine
-    cfg = get_smoke_config("llama3-8b").replace(dtype="float32")
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
-
-    def make(**kw):
-        kw = {"batch": 1, "capacity": 32, **kw}
-        return cfg, ServeEngine(cfg, params, **kw)
-    return make
-
-
-def test_serve_submit_rejects_past_capacity(serve_engine_factory):
-    cfg, eng = serve_engine_factory(capacity=16)
-    prompt = np.arange(8) % cfg.vocab_size
-    with pytest.raises(AdmissionRejected) as ei:
-        eng.submit(prompt, max_new=16)         # 8 + 16 > 15 positions
-    assert ei.value.reason == "capacity"
-    assert eng.submit(prompt, max_new=7) == 0  # 8 + 7 == 15 fits
-
-
-def test_serve_submit_queue_full_and_deadline_shed(serve_engine_factory):
-    cfg, eng = serve_engine_factory(max_queue=1)
-    prompt = np.arange(4) % cfg.vocab_size
-    eng.submit(prompt, max_new=4)              # takes the single slot
-    eng.step()
-    rid_q = eng.submit(prompt, max_new=4, deadline=2)   # waits in queue
-    with pytest.raises(AdmissionRejected) as ei:
-        eng.submit(prompt, max_new=4)
-    assert ei.value.reason == "queue_full"
-    for _ in range(4):
-        eng.step()
-    req = eng.requests[rid_q]
-    assert req.shed and req.done and eng.stats["shed"] == 1
-    # the shed request freed its queue slot: a new submit is accepted
-    rid2 = eng.submit(prompt, max_new=4)
-    assert rid2 != rid_q
 
 
 # ---------------------------------------------------------------------------

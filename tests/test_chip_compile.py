@@ -1,4 +1,4 @@
-"""Compile the simulator's engine and the Pallas kernels for a TPU v5e.
+"""Compile the simulator's engine for a TPU v5e.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology and raises what the chip's compiler would raise (tiling, VMEM,
@@ -10,7 +10,6 @@ import os
 import re
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
@@ -152,30 +151,3 @@ def test_engine_compiles_full_server(one_chip):
     assert re.search(r"body=%", hlo)
     assert _loop_relayouts(hlo, {4096 * cfg.wram_words,
                                  4096 * cfg.mram_words}) == []
-
-
-def test_alu_exec_kernel_compiles(one_chip):
-    from repro.kernels.alu_exec.ops import alu_exec
-    x = _spec((4096 * 16,), jnp.int32, one_chip)
-    alu_exec.lower(x, x, x, interpret=False).compile()
-
-
-def test_flash_attention_kernel_compiles(one_chip):
-    from repro.kernels.flash_attention.ops import flash_attention_op
-    # llama3-8b attention widths: 32 query heads over 8 KV heads of 128
-    q = _spec((1, 1024, 32, 128), jnp.bfloat16, one_chip)
-    kv = _spec((1, 1024, 8, 128), jnp.bfloat16, one_chip)
-    flash_attention_op.lower(q, kv, kv, interpret=False).compile()
-
-
-def test_ssd_scan_kernel_compiles(one_chip):
-    from repro.kernels.ssd_scan.ops import ssd_scan_op
-    # mamba2-130m widths: 24 heads of 64, state 128, chunk 256
-    BH, S, P, N = 24, 512, 64, 128
-    ssd_scan_op.lower(
-        _spec((BH, S, P), jnp.float32, one_chip),
-        _spec((BH, S), jnp.float32, one_chip),
-        _spec((BH,), jnp.float32, one_chip),
-        _spec((BH, S, N), jnp.float32, one_chip),
-        _spec((BH, S, N), jnp.float32, one_chip),
-        chunk=256, interpret=False).compile()

@@ -1,6 +1,5 @@
 """Cycle-level engine: semantics + microarchitectural timing properties."""
 import numpy as np
-import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import engine
@@ -267,29 +266,3 @@ def test_counters_partition_cycles():
     total = (int(st_["c_active"][0]) + int(st_["c_idle_mem"][0])
              + int(st_["c_idle_rev"][0]) + int(st_["c_idle_rf"][0]))
     assert total == int(st_["cycle"][0])
-
-
-@pytest.mark.parametrize("flat", [False, True])
-def test_make_step_closure_matches_run(monkeypatch, flat):
-    """The back-compat closure step takes and returns ``make_state``'s
-    ``[D, W]`` WRAM and ``[D, M]`` MRAM, whatever the carry form, and
-    simulates what ``run`` does, lane for lane."""
-    import jax
-
-    import repro.workloads as wl
-    if flat:
-        monkeypatch.setattr(engine, "FLAT_CARRY_WORDS", 0)
-    cfg = DPUConfig(n_dpus=2, n_tasklets=16, mram_bytes=1 << 16)
-    W = wl.get("VA")
-    hd = W.host_data(cfg, 0.02, 0)
-    binary = W.build(8).binary(cfg.iram_instrs)
-    wram = np.zeros((2, 16), np.int32)
-    wram[:, :hd.args.shape[1]] = hd.args
-    step, cond = engine.make_step(cfg, binary)
-    st0 = engine.make_state(cfg, binary, wram, hd.mram, 8)
-    out = jax.jit(lambda s: jax.lax.while_loop(cond, step, s))(st0)
-    want = engine.run(cfg, binary, wram, hd.mram, n_threads=8)
-    assert out["wram"].shape == (2, cfg.wram_words)
-    assert out["mram"].shape == hd.mram.shape
-    for k in want:
-        assert np.array_equal(np.asarray(out[k]), want[k]), k

@@ -313,28 +313,26 @@ def test_fault_stream_identical_across_modes():
     assert np.array_equal(m_in, m_as)
 
 
-# ---- serving: degraded PIM pool never loses a request ----------------------
+# ---- serving: a degraded PIM pool refuses to tick ---------------------------
 
 def test_serve_engine_survives_midstream_pool_fault():
-    import jax
-
-    from repro.configs.base import get_smoke_config
-    from repro.models import transformer as T
-    from repro.serve.engine import ServeEngine
+    """A decode pool's healthy tick charges the timeline's kernel phase,
+    a degraded one stretches by ``D / healthy``, and once ``disable_dpus``
+    drops the pool below ``min_fraction`` mid-stream, ``tick`` raises
+    :class:`DpuFaultError` and charges nothing, so its holder can
+    reschedule the stream instead of losing it."""
     from repro.serve.pim_pool import PimDecodePool
 
-    cfg = get_smoke_config("llama3-8b").replace(dtype="float32")
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
     pim = PIMSystem(_cfg(), faults=FaultPlan())
-    pool = PimDecodePool(pim, min_fraction=0.5)
-    eng = ServeEngine(cfg, params, batch=2, capacity=64, pim_pool=pool)
-    rng = np.random.default_rng(0)
-    rids = [eng.submit(rng.integers(0, cfg.vocab_size, 5), max_new=4)
-            for _ in range(4)]
-    eng.step()                      # healthy tick
-    pim.disable_dpus([0, 1, 2])     # pool collapses below the 50% floor
-    outs = eng.run()
-    assert set(outs) == set(rids)   # no request lost
-    assert all(len(v) == 4 for v in outs.values())
-    assert eng.stats["pim_ticks"] >= 1 and eng.stats["host_ticks"] >= 1
-    assert pim.timeline.kernel > 0.0
+    pool = PimDecodePool(pim, tick_seconds=1e-4, min_fraction=0.5)
+    assert pool.tick() == 1e-4                  # healthy tick
+    assert pim.timeline.kernel == pytest.approx(1e-4)
+    pim.disable_dpus([0])                       # 3 of 4 lanes survive
+    assert pool.tick() == pytest.approx(1e-4 / 0.75)
+    charged = pim.timeline.kernel
+    assert charged == pytest.approx(1e-4 + 1e-4 / 0.75)
+    pim.disable_dpus([1, 2])                    # 1 of 4: under the floor
+    with pytest.raises(DpuFaultError) as ei:
+        pool.tick()
+    assert ei.value.report.kind == "pool_degraded"
+    assert pim.timeline.kernel == charged and pool.ticks == 2
