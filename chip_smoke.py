@@ -13,8 +13,8 @@ Phases, each run twice (cold, then warm):
 * one rank: Table I DPUs, 64 of them with 16 tasklets: VA, BFS
   (multi-kernel, host-priced collectives), SSORT (alltoall) and GEMVS on
   the HBM-PIM command backend;
-* full server: VA on PrIM's 2,560-DPU server (40 ranks of 64), whose DPU
-  axis pads to the 4,096-lane bucket.
+* full server: VA on PrIM's 2,560-DPU server (40 ranks of 64), on 2,560
+  engine lanes (its DPU bucket).
 
 Each run prints one ``bring-up`` line: wall and compile seconds, hits in
 JAX's persistent compile cache, simulated cycles and issued instructions,
@@ -54,8 +54,9 @@ ONE_RANK_RUNS = [
     ("GEMVS", 1.0, dict(mram_bytes=1 << 20, backend="hbmpim_cmd")),
 ]
 SERVER_DPUS, SERVER_RANKS = 2560, 40    # PrIM's server (arXiv:2105.03814)
-# 0.25 took 191 s per run on a TPU v5e (3.4 ms per loop iteration at
-# 4,096 lanes); 0.1 keeps each run near a minute and a half
+# 0.25 took 191 s per run on a TPU v5e (3.4 ms per loop iteration on
+# 4,096 lanes, 1,536 of them padded); 0.1 kept each run near a minute
+# and a half
 SERVER_SCALE = 0.1
 SERVER_MRAM_BYTES = 64 << 10
 # VA at SERVER_SCALE on one DPU (CPU run): its timing does not depend on

@@ -17,12 +17,16 @@ The cache kills that three ways:
   baked-in closure constants, so two different kernels of the same
   padded shape share one executable.
 * **Shape buckets** — the program axis and the DPU axis are padded to
-  power-of-two buckets with masked inactive lanes (``DONE`` status,
-  ``STOP``-filled program tail), so ``host.launch(dpus=...)`` subsets of
-  any size — and sweeps over system sizes — land on a handful of
-  executables instead of one per exact shape.  Padded DPU lanes never
-  issue, never touch DRAM, and are sliced off before results are
-  returned, so bucketed runs are bit-exact vs. unpadded ones.
+  buckets with masked inactive lanes (``DONE`` status, ``STOP``-filled
+  program tail), so ``host.launch(dpus=...)`` subsets of any size — and
+  sweeps over system sizes — land on a handful of executables instead of
+  one per exact shape.  The program axis takes powers of two.  The DPU
+  axis takes powers of two up to :data:`DPU_LANE_TILE` DPUs and, above,
+  multiples of that lane tile on a quarter-octave ladder (at most four
+  buckets per octave; see :func:`dpu_bucket`), so a large system such as
+  PrIM's 2,560-DPU server runs on as many lanes as it has DPUs.  Padded
+  DPU lanes never issue, never touch DRAM, and are sliced off before
+  results are returned, so bucketed runs are bit-exact vs. unpadded ones.
 
 State buffers are donated to XLA (they are rebuilt per launch), avoiding
 a full state copy per step-loop entry.
@@ -64,6 +68,8 @@ from repro.obs import spans
 PROGRAM_BUCKET_FLOOR = 64
 #: smallest padded DPU-axis width
 DPU_BUCKET_FLOOR = 1
+#: the TPU's lane tile: DPU buckets above it are multiples of it
+DPU_LANE_TILE = 128
 
 
 #: persistent XLA cache used when ``JAX_COMPILATION_CACHE_DIR`` is unset —
@@ -102,7 +108,21 @@ def program_bucket(n_instrs: int, capacity: int) -> int:
 
 
 def dpu_bucket(n_dpus: int) -> int:
-    return pow2_bucket(n_dpus, DPU_BUCKET_FLOOR)
+    """Padded DPU-axis width for ``n_dpus`` DPUs.
+
+    Up to :data:`DPU_LANE_TILE` DPUs: the smallest power of two (at
+    least :data:`DPU_BUCKET_FLOOR`).  Above: ``n_dpus`` rounded up to a
+    multiple of the lane tile or of a quarter of ``p``, the largest power
+    of two below ``n_dpus``, whichever is larger: 256, 384, 512, 640,
+    768, 896, 1024, 1280, ..., 2560, 3072, 3584, 4096.  At most four
+    buckets per octave; from 513 DPUs on, under a fifth of the lanes are
+    masked."""
+    n = int(n_dpus)
+    if n <= DPU_LANE_TILE:
+        return pow2_bucket(n, DPU_BUCKET_FLOOR)
+    p = 1 << ((n - 1).bit_length() - 1)     # p < n <= 2p
+    step = max(DPU_LANE_TILE, p // 4)
+    return -(-n // step) * step
 
 
 def _lanes(cfg: DPUConfig, pad: bool) -> int:

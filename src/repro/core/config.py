@@ -152,7 +152,7 @@ class DPUConfig:
         executable.  Host/interconnect knobs (transfer rates, rank
         topology, fabric pricing) never enter the traced step, and the
         axes the cache buckets or keys separately are excluded here:
-        ``n_dpus`` (padded to a power-of-two bucket), ``n_tasklets``
+        ``n_dpus`` (padded to a DPU bucket), ``n_tasklets``
         (the effective thread count is keyed explicitly), ``mram_bytes``
         (the actual MRAM image width is keyed) and ``iram_instrs``
         (the program length is bucketed).  New fields are conservatively

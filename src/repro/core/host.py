@@ -513,7 +513,7 @@ class PIMSystem:
         """Compile the engine executable a later :meth:`launch` will use
         (cold XLA compile off the measured path).  With ``dpus`` the
         subset's DPU bucket is warmed instead — any other subset size in
-        the same power-of-two bucket shares the executable.  Returns the
+        the same DPU bucket shares the executable.  Returns the
         compile-cache key."""
         from repro.core import compile_cache
         cfg = self.cfg
@@ -552,9 +552,10 @@ class PIMSystem:
         fault reports, never silently wrong data.
 
         Every launch goes through ``repro.core.compile_cache``: the DPU
-        axis is padded to a power-of-two bucket, so subsets of any size
-        within one bucket (and relaunches of any same-shaped kernel)
-        reuse a warm XLA executable instead of recompiling."""
+        axis is padded to a bucket (``compile_cache.dpu_bucket``), so
+        subsets of any size within one bucket (and relaunches of any
+        same-shaped kernel) reuse a warm XLA executable instead of
+        recompiling."""
         D = self.cfg.n_dpus
         T = n_threads or self.cfg.n_tasklets
         if args.shape[0] != D or mram.shape[0] != D:

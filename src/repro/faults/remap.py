@@ -7,7 +7,7 @@ re-executed on surviving lanes — spare lanes first, then live workers —
 by *relocating the shard's args/MRAM rows to the survivor's lane* and
 launching the survivor subset.  Each recovery round is an ordinary
 subset launch through the compiled-engine cache, so it lands in a warm
-power-of-two DPU bucket instead of recompiling.
+DPU bucket instead of recompiling.
 
 Two properties make this sound for the workloads that use it:
 

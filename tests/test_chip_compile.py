@@ -140,14 +140,15 @@ def test_hbmpim_cmd_gemvs_compiles(one_chip):
 
 
 def test_engine_compiles_full_server(one_chip):
-    """2,560 DPUs (40 ranks x 64) pad to the 4,096-lane bucket.  Past
+    """2,560 DPUs (40 ranks x 64) run on a 2,560-lane bucket, a multiple
+    of the 128-lane tile, with no padded lane.  Past
     ``engine.FLAT_CARRY_WORDS`` the engine keeps WRAM and MRAM flat
     through its loop: no WRAM- or MRAM-sized relayout in it."""
     cfg = DPUConfig(n_dpus=2560, n_ranks=40, n_tasklets=16,
                     mram_bytes=1 << 16)
-    assert compile_cache.dpu_bucket(cfg.n_dpus) == 4096
+    assert compile_cache.dpu_bucket(cfg.n_dpus) == 2560
     hlo = _compile_engine(cfg, _va(cfg), cfg.mram_words, 16,
                           one_chip).as_text()
     assert re.search(r"body=%", hlo)
-    assert _loop_relayouts(hlo, {4096 * cfg.wram_words,
-                                 4096 * cfg.mram_words}) == []
+    assert _loop_relayouts(hlo, {2560 * cfg.wram_words,
+                                 2560 * cfg.mram_words}) == []

@@ -173,11 +173,12 @@ def test_driver_takes_the_backends_carry_form(monkeypatch, backend, flat):
 
 
 def test_scalar_carry_is_flat_past_the_threshold():
-    """One rank keeps ``[D, W]`` lane memories; the 4,096-lane server's
-    are flat (shapes only: nothing is allocated)."""
+    """One rank keeps ``[D, W]`` lane memories; the 2,560-lane server's
+    and other wide systems' are flat (shapes only: nothing is
+    allocated)."""
     be = backends.get("scalar")
     for lanes, flat in ((64, False), (512, False), (1024, True),
-                        (4096, True)):
+                        (2560, True), (4096, True)):
         st = {"status": jax.ShapeDtypeStruct((lanes, 16), np.int32),
               "wram": jax.ShapeDtypeStruct((lanes, 1 << 14), np.int32),
               "mram": jax.ShapeDtypeStruct((lanes, 1 << 16), np.int32)}
@@ -242,6 +243,69 @@ def test_bucket_shapes():
         b = compile_cache.program_bucket(n, cap)
         assert b <= cap and (b & (b - 1)) == 0
         assert b >= min(n + 1, cap)  # room for a STOP pad slot
+
+
+@pytest.mark.parametrize("n,bucket", [
+    *((n, 1 << (n - 1).bit_length()) for n in (1, 2, 3, 5, 63, 64, 65,
+                                                100, 127, 128)),
+    (129, 256), (300, 384), (640, 640), (2556, 2560), (2560, 2560),
+    (2561, 3072), (4096, 4096)])
+def test_dpu_bucket_rule(n, bucket):
+    """Powers of two up to 128 DPUs (one rank's 64 keep bucket 64);
+    above, multiples of the 128-lane tile on which PrIM's two systems
+    (640 and 2,556 DPUs) and the 2,560-DPU server land without a spare
+    rung."""
+    assert compile_cache.dpu_bucket(n) == bucket
+
+
+def test_dpu_bucket_is_the_power_of_two_up_to_128():
+    assert [compile_cache.dpu_bucket(n) for n in range(1, 129)] == \
+        [1 << (n - 1).bit_length() for n in range(1, 129)]
+
+
+def test_dpu_bucket_holds_n_on_the_lane_tile():
+    for n in range(1, 20001):
+        b = compile_cache.dpu_bucket(n)
+        assert b >= n, n
+        assert n <= 128 or b % 128 == 0, n
+
+
+def test_dpu_bucket_has_four_per_octave_at_most():
+    buckets = {compile_cache.dpu_bucket(n) for n in range(1, 1 << 16)}
+    for k in range(17):
+        octave = [x for x in buckets if 1 << k < x <= 2 << k]
+        assert len(octave) <= 4, (k, sorted(octave))
+
+
+def test_padded_bit_exact_off_pow2_bucket():
+    """300 DPUs on 384 lanes, a bucket that is no power of two: the
+    padded launch matches the exact-shape run on every state array and
+    counts its 84 masked lanes in ``lane_cycles``."""
+    cfg = DPUConfig(n_dpus=300, n_tasklets=2, mram_bytes=1 << 12)
+    p = Program("ids", 2)
+    r = p.reg("r")
+    from repro.core.asm import DPU_ID, N_DPUS, ZERO
+    p.mul(r, DPU_ID, 3)
+    p.add(r, r, N_DPUS)
+    p.sw(ZERO, 64, r)
+    p.stop()
+    binary = p.binary(cfg.iram_instrs)
+    wram = np.zeros((300, 16), np.int32)
+    mram = np.zeros((300, cfg.mram_words), np.int32)
+    assert compile_cache.dpu_bucket(300) == 384
+    compile_cache.clear()
+    padded = compile_cache.run(cfg, binary, wram, mram, 2, pad=True)
+    s = compile_cache.stats()
+    compile_cache.clear()
+    exact = compile_cache.run(cfg, binary, wram, mram, 2, pad=False)
+    compile_cache.clear()
+    assert padded["status"].shape == exact["status"].shape == (300, 2)
+    for k in exact:
+        assert np.array_equal(padded[k], exact[k]), k
+    assert list(padded["wram"][[0, 1, 299], 16]) == [300, 303, 1197]
+    cycles = int(padded["cycle"].max())
+    assert s["lane_cycles"] == 384 * cycles
+    assert s["dpu_cycles"] == int(padded["cycle"].sum())
 
 
 def test_bucket_floor_knob():
