@@ -9,10 +9,11 @@ with the program, once the window has closed:
   with numpy alone;
 * its timing: for a sample of the window's simulations and of their
   DPUs, drawn from the run seed, each launch's cycles and issued
-  instructions on that DPU, against the plain DPU model
-  (``reference/dpu.py``) running the cell's kernel from the inputs the
-  workload's reference gives for that launch.  The DPU that the program
-  reports slowest in each launch is always in the sample;
+  instructions on that DPU, against the plain timing model that the
+  configuration names (``reference/<timing_model>.py``: ``dpu.py``, the
+  UPMEM DPU running the cell's kernel, where it names none), run from
+  what the workload's reference gives for that launch.  The DPU that the
+  program reports slowest in each launch is always in the sample;
 * its simulated statistics (cycles, issued instructions, launches and
   the timeline's phase totals), against the values pinned for its data
   seed in ``expected/<cell>.json``: a regression check of the host's
@@ -26,11 +27,9 @@ from typing import Dict, List
 
 import numpy as np
 
-from pimbench.reference import dpu as dpu_model
-
 LIMITS = {"failed_simulations": 0, "output_words_wrong": 0,
           "timing_wrong": 0, "statistics_wrong": 0}
-#: simulations of a run, and DPUs of each launch, held to the DPU model
+#: simulations of a run, and DPUs of each launch, held to the timing model
 SAMPLE_SIMS, SAMPLE_DPUS = 2, 3
 
 
@@ -50,20 +49,19 @@ def stats_wrong(got, want: dict) -> int:
 
 def timing_wrong(cell, sim, rng: np.random.Generator) -> int:
     """Launches missing or extra, and sampled (launch, DPU) pairs whose
-    cycles or issued instructions differ from the plain DPU model."""
-    dpu, t = cell.dpu(), int(cell.dpu()["n_tasklets"])
-    kernel = dpu_model.kernel(f"{cell.traffic['workload']}.t{t}")
+    cycles or issued instructions differ from the plain timing model."""
+    dpu, workload = cell.dpu(), cell.traffic["workload"]
+    model = cell.timing_model()
     want = cell.reference().launches(dpu, cell.traffic["sizes"],
                                      sim.data_seed)
     wrong = abs(len(want) - len(sim.launches))
     d = int(dpu["n_dpus"])
-    for (args, mram), got in zip(want, sim.launches):
+    for launch, got in zip(want, sim.launches):
         sample = set(rng.choice(d, min(SAMPLE_DPUS, d), replace=False)
                      .tolist())
         sample.add(int(np.argmax(got.cycles)))
         for k in sorted(sample):
-            ref = dpu_model.run(kernel, args[k], mram[k].tolist(), dpu=dpu,
-                                tasklets=t, dpu_id=k, n_dpus=d)
+            ref = model.time_launch(dpu, workload, launch, k)
             wrong += ref != (int(got.cycles[k]), int(got.issued[k]))
     return wrong
 

@@ -6,11 +6,13 @@
 
 In one process, each ``--sound`` seed runs the cell's closed loop for
 ``--seconds`` as configured, and each ``--control`` seed runs it with the
-control: the DPU's revolver distance cut from 11 cycles to 10, a timing
-model that still computes right answers but at other cycle counts, the
-step a change for speed might take by accident.  Prints one JSON line
-per seed with ``correct`` and each number compared.  The benchmark's own
-runs never run the control.
+control: the departure from the configuration that the configuration
+file names under ``control``, read by its own timing model; where it
+names none, the DPU's revolver distance cut from 11 cycles to 10.  Such
+a departure still computes right answers, but at other cycle counts:
+the step a change for speed might take by accident.  Prints one JSON
+line per seed with ``correct`` and each number compared.  The
+benchmark's own runs never run the control.
 """
 import time
 
@@ -24,8 +26,13 @@ from pathlib import Path  # noqa: E402
 ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-#: the control's departure from the configuration
+#: the control's departure from a configuration that names none
 CONTROL = {"revolver_cycles": 10}
+
+
+def departure(cell) -> dict:
+    """The control's departure from the cell's configuration."""
+    return cell.config.get("control", CONTROL)
 
 
 def main(argv=None) -> int:
@@ -40,7 +47,7 @@ def main(argv=None) -> int:
     harness.use_compile_cache(ROOT / ".jax_cache")
     cell = spec.load_cell(args.workload)
     runs = ([("sound", s, None) for s in args.sound]
-            + [("control", s, CONTROL) for s in args.control])
+            + [("control", s, departure(cell)) for s in args.control])
     for kind, seed, override in runs:
         r = harness.measure(cell, seed, args.seconds, False, t0=T0,
                             dpu_override=override, log=lambda _: None)

@@ -115,14 +115,16 @@ class Program:
         self.cfg = DPUConfig(**self.dpu)
         self.traffic = cell.traffic
         self.workload = wl.get(self.traffic["workload"])
-        self.width = cell.reference().words(self.dpu, self.traffic["sizes"])
+        self.reference = cell.reference()
+        self.width = self.reference.words(self.dpu, self.traffic["sizes"])
+        self._warmup = cell.warmup()
 
-    def prewarm(self):
-        """Compile (or load from the persistent cache) the cell's one
-        engine executable, simulating nothing."""
-        t = self.cfg.n_tasklets
-        binary = self.workload.build(t).binary(self.cfg.iram_instrs)
-        self.PIMSystem(self.cfg).prewarm(binary, n_threads=t)
+    def prewarm(self) -> list:
+        """Compile (or load from the persistent cache) the engine
+        executables of the cell's launches, simulating nothing, by the
+        warm-up that the cell's backend needs.  Returns their
+        compile-cache keys."""
+        return self._warmup.prewarm(self)
 
     def simulate(self, sim: Sim, spans: bool = False):
         """One user-level simulation; fills ``sim`` and never raises.

@@ -6,10 +6,11 @@
 Runs each data seed once through ``Workload.run`` and prints one JSON line
 per seed: the statistics ``check.py`` compares, and whether the output
 matched the plain reference.  ``--write`` merges them into
-``expected/<cell>.json`` and writes the cell's kernel, as the program
-assembles it, to ``reference/kernels/<workload>.t<tasklets>.txt`` for the
-plain DPU model.  Runs on any platform: the statistics are meant to be
-the same on the CPU and on the chip.
+``expected/<cell>.json``; where the configuration's timing model runs a
+kernel file (``dpu``), it also writes the cell's kernel, as the program
+assembles it, to ``reference/kernels/<workload>.t<tasklets>.txt``.
+Runs on any platform: the statistics are meant to be the same on the
+CPU and on the chip.
 """
 import argparse
 import json
@@ -67,7 +68,8 @@ def main(argv=None) -> int:
             return 1
         pinned[str(ds)] = sim.stats
     if args.write:
-        write_kernel(prog)
+        if hasattr(cell.timing_model(), "kernel"):   # it reads a kernel file
+            write_kernel(prog)
         path = cell.expected_path()
         doc = (json.loads(path.read_text()) if path.exists()
                else {"data_seeds": {}})

@@ -24,6 +24,9 @@ Table I) and shares no code with the simulator:
   tasklet until all live tasklets have reached it, releasing them for
   the next cycle;
 * the kernel's cycles end one cycle after its last STOP issues.
+
+The check calls :func:`time_launch`, the entry point that every timing
+model under ``reference/`` has.
 """
 from __future__ import annotations
 
@@ -237,3 +240,14 @@ def run(prog: Kernel, args: Sequence[int], mram: list, *, dpu: dict,
             cycle = c + 1
         port = max(port - 1, 0)
     return cycle, issued
+
+
+def time_launch(dpu: dict, workload: str, launch, k: int) -> Tuple[int, int]:
+    """``(cycles, issued)`` of DPU ``k`` in ``launch``, an element of the
+    workload's reference ``launches()``: its arguments and MRAM words,
+    each ``(n_dpus, ...)``, run through the workload's kernel at the
+    configuration's tasklets."""
+    args, mram = launch
+    t = int(dpu["n_tasklets"])
+    return run(kernel(f"{workload}.t{t}"), args[k], mram[k].tolist(),
+               dpu=dpu, tasklets=t, dpu_id=k, n_dpus=int(dpu["n_dpus"]))

@@ -10,6 +10,9 @@ from typing import List
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
+#: backends on which a workload with a native command path launches
+#: HBM-PIM CRF command streams (``repro.core.hbmpim``)
+HBM_PIM = ("hbmpim", "hbmpim_cmd")
 
 
 @dataclass
@@ -35,6 +38,23 @@ class Cell:
 
     def reference(self) -> ModuleType:
         return load_module(HERE / "reference" / f"{self.traffic['workload']}.py")
+
+    def timing_model(self) -> ModuleType:
+        """The plain timing model that the check runs sampled launches on:
+        ``reference/<timing_model>.py`` as the configuration names it,
+        ``dpu`` where it names none."""
+        name = self.config.get("timing_model", "dpu")
+        return load_module(HERE / "reference" / f"{name}.py")
+
+    def warmup(self) -> ModuleType:
+        """How set-up warms the program's executables, as the cell's
+        backend launches them: ``warmup/crf.py`` on an HBM-PIM backend
+        for a workload whose reference gives its CRF command programs
+        (``programs``: GEMVS takes its all-bank command path there),
+        ``warmup/kernel.py``, the workload's DPU kernel, otherwise."""
+        crf = (self.config["dpu"].get("backend") in HBM_PIM
+               and hasattr(self.reference(), "programs"))
+        return load_module(HERE / "warmup" / ("crf.py" if crf else "kernel.py"))
 
 
 def load_module(path: Path) -> ModuleType:

@@ -1,11 +1,13 @@
 """The benchmark's check catches what it is there to catch: CPU runs of
-small cells with the timed path broken underneath, and with the control
-in the program's place.  Each drives the rest of a run (set-up, the
-window, the references) without looking for a chip, and sees ``correct``
-come out false.
+small cells (VA and BFS on UPMEM DPUs, GEMVS on HBM-PIM banks in
+all-bank mode) with the timed path broken underneath, and with the
+control in the program's place.  Each drives the rest of a run (set-up,
+the window, the references) without looking for a chip, and sees
+``correct`` come out false.
 
     python -m pytest tests/pimbench
 """
+import copy
 import json
 import sys
 import time
@@ -22,17 +24,28 @@ from pimbench import control, harness, spec  # noqa: E402
 CONFIG = json.loads(
     (ROOT / "pimbench" / "configs" / "upmem-rank64.json").read_text())
 DPU = {**CONFIG["dpu"], "n_dpus": 4}
+#: HBM-PIM all-bank at 4 banks, timed by the bank model; 65 CRF slots
+#: split each chunk of GEMVS into launches of 8 groups and of 1, two
+#: program lengths for the warm-up to cover
+HBM = {"dpu": {**DPU, "backend": "hbmpim_cmd", "hbm_lanes": 16,
+               "hbm_crf_slots": 65, "mram_bw_scale": 1.0,
+               "coalesced_bw_mult": 3.4},
+       "timing_model": "hbm_bank", "control": {"t_rcd": 8}}
 SMALL = {
-    "VA": dict(scale=0.02, mram_bytes=64 << 10,
-               sizes={"elements_per_dpu": 288}),
-    "BFS": dict(scale=0.02, mram_bytes=1 << 20, sizes={"vertices": 96}),
+    "VA": ({"dpu": DPU}, dict(scale=0.02, mram_bytes=64 << 10,
+                              sizes={"elements_per_dpu": 288})),
+    "BFS": ({"dpu": DPU}, dict(scale=0.02, mram_bytes=1 << 20,
+                               sizes={"vertices": 96})),
+    "GEMVS": (HBM, dict(scale=0.075, mram_bytes=64 << 10,
+                        sizes={"rows": 144})),
 }
 
 
 def small_cell(workload: str) -> spec.Cell:
+    config, traffic = SMALL[workload]
     return spec.Cell(
-        name=f"small.{workload}", chips=1, config={"dpu": dict(DPU)},
-        traffic={"workload": workload, "seed_pool": 2, **SMALL[workload]},
+        name=f"small.{workload}", chips=1, config=copy.deepcopy(config),
+        traffic={"workload": workload, "seed_pool": 2, **traffic},
         end_to_end=[{"name": "setup_s", "unit": "s"},
                     {"name": "sim_minstr_per_s", "unit": "Minstr/s"}],
         per_layer=[])
@@ -94,12 +107,16 @@ def _cycles_altered(out, mram):
     out["cycle"][:] += 1
 
 
-def test_sound_run_is_correct(pinned):
+def test_sound_run_is_correct(pinned, monkeypatch):
+    from repro.core import compile_cache
+    # the pins built every executable: only the run's warm-up rebuilds them
+    monkeypatch.setattr(compile_cache, "_ENTRIES", {})
     r, checks = _run(*pinned)
     assert r["correct"] and r["attempted"] >= 1 and r["failed"] == 0
     assert checks == {"failed_simulations": 0, "output_words_wrong": 0,
                       "timing_wrong": 0, "statistics_wrong": 0}
     assert r["metrics"]["sim_minstr_per_s"]["value"] > 0
+    assert r["run"]["window"]["engine_builds"] == 0, "set-up warmed them"
 
 
 @pytest.mark.parametrize("fault", [_unchanged, _half_left_out,
@@ -122,7 +139,7 @@ def test_exchange_left_out_is_not_correct(pinned, monkeypatch):
 
 
 def test_control_is_not_correct(pinned):
-    r, checks = _run(*pinned, dpu_override=control.CONTROL)
+    r, checks = _run(*pinned, dpu_override=control.departure(pinned[0]))
     assert not r["correct"]
-    assert checks["timing_wrong"] > 0, "the plain DPU model sees it"
+    assert checks["timing_wrong"] > 0, "the plain timing model sees it"
     assert checks["output_words_wrong"] == 0, "the control computes right"

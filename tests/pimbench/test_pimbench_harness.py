@@ -1,5 +1,6 @@
 """CPU tests of the benchmark's harness: cells, trace reduction, window,
-and the plain DPU model that the check times the program against.
+warm-up, and the plain timing models (the UPMEM DPU, the HBM-PIM bank)
+that the check times the program against.
 
     python -m pytest tests/pimbench
 """
@@ -18,6 +19,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from pimbench import harness, spec, trace, window  # noqa: E402
 from pimbench.reference import dpu as dpu_model  # noqa: E402
+from pimbench.reference import hbm_bank  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -37,8 +39,12 @@ def test_every_cell_resolves_to_its_files(name):
     assert ref.words(cell.dpu(), cell.traffic["sizes"]) * 4 \
         <= cell.traffic["mram_bytes"]
     assert callable(ref.launches)
-    t = cell.config["dpu"]["n_tasklets"]
-    assert dpu_model.kernel(f"{cell.traffic['workload']}.t{t}")
+    model = cell.timing_model()
+    assert callable(model.time_launch)
+    if hasattr(model, "kernel"):                 # it runs a kernel file
+        t = cell.config["dpu"]["n_tasklets"]
+        assert model.kernel(f"{cell.traffic['workload']}.t{t}")
+    assert callable(cell.warmup().prewarm)
     names = {m["name"] for m in cell.end_to_end + cell.per_layer}
     assert {"setup_s", "sim_minstr_per_s"} <= names
     for m in names:
@@ -203,3 +209,163 @@ def test_dpu_model_dma_by_hand():
                    ("SW", 0, 19, 5, 0, True), sdma, stop], [], out,
                   dpu=DPU, tasklets=1, dpu_id=0, n_dpus=1)
     assert out[:2] == [18, 9]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_warmup_keeps_the_kernel_prewarm_key(name, monkeypatch):
+    """The warm-up that each cell's configuration names builds the
+    compile-cache key of the workload's kernel prewarmed at the
+    configuration's tasklets.  Nothing is compiled: the launch is cut to
+    the key."""
+    from repro.core import compile_cache
+
+    def launch(cfg, binary, wram, mram, T, be, pad, **_):
+        P = compile_cache.program_bucket(binary.n_instrs,
+                                         binary.opcode.shape[0])
+        entry, hit = compile_cache._get_entry(
+            cfg, be, P, compile_cache._lanes(cfg, pad), T, mram.shape[1])
+        return entry, hit, None, 0
+
+    monkeypatch.setattr(compile_cache, "_launch", launch)
+    monkeypatch.setattr(compile_cache, "_ENTRIES", {})
+    prog = harness.Program(spec.load_cell(name))
+    t = prog.cfg.n_tasklets
+    binary = prog.workload.build(t).binary(prog.cfg.iram_instrs)
+    want = prog.PIMSystem(prog.cfg).prewarm(binary, n_threads=t)
+    assert prog.prewarm() == [want]
+    assert want[0] == "scalar" and want[3:5] == (
+        compile_cache.dpu_bucket(prog.cfg.n_dpus), t)
+
+
+def _hbm_dpu(**over) -> dict:
+    """The rank's DPU block on the HBM-PIM command backend, with every
+    number the bank model reads stated."""
+    return {**DPU, "backend": "hbmpim_cmd", "hbm_lanes": 16,
+            "hbm_crf_slots": 64, "mram_bw_scale": 1.0,
+            "coalesced_bw_mult": 3.4, **over}
+
+
+def _crf(commands):
+    """A bank-model command stream as the program's CRF program."""
+    from repro.core import hbmpim
+    code = {"bank": hbmpim.bank, "grf_a": hbmpim.grf_a,
+            "grf_b": hbmpim.grf_b, "srf": hbmpim.srf}
+    p = hbmpim.CrfProgram()
+    for op, *ops in commands:
+        if op == "JUMP":
+            p.jump(*ops)
+        elif op == "EXIT":
+            p.exit_()
+        elif op == "NOP":
+            p.nop()
+        else:
+            getattr(p, op.lower())(*(code[kind](i) for kind, i in ops))
+    return p
+
+
+def test_hbm_bank_model_by_hand():
+    from repro.core import hbmpim
+    from repro.core.config import DPUConfig
+    from repro.core.host import PIMSystem
+    dpu = _hbm_dpu(n_dpus=1, mram_bytes=4096)
+    hand = [
+        # 1 + a row miss (tRP + tRCD + tCL = 48 DRAM cycles = 14) + the
+        # burst (64 bytes at 2 x 3.4 bytes a cycle = 10): 25
+        ("MOV", ("grf_a", 0), ("bank", 3)),
+        # 1 + the destination on the open row (tCL = 16 = 5) + 10: 16
+        ("ADD", ("bank", 3), ("grf_a", 0), ("srf", 1)),
+        # 1 + a on the open row (5 + 10) + a row miss for row 4 (14 +
+        # 10): 40
+        ("MAC", ("bank", 4), ("bank", 3), ("srf", 1)),
+        ("NOP",),
+        ("JUMP", 3, 2),          # NOP and JUMP three times each: 6
+        ("EXIT",),               # 1
+    ]
+    bank = list(range(100, 100 + 1024))
+    srf = [0, 7, 0, 0, 0, 0, 0, 0]
+    got = list(bank)
+    # 88 cycles; 16 issued for each of the three compute commands, one
+    # for each of the seven others
+    assert hbm_bank.run(hand, list(srf), got, dpu=dpu) == (88, 55)
+    row3 = [v + 7 for v in bank[48:64]]
+    assert got[48:64] == row3
+    assert got[64:80] == [z + 7 * x for z, x in zip(bank[64:80], row3)]
+    assert got[80:] == bank[80:] and got[:48] == bank[:48]
+    # the program's command engine agrees, cycle for cycle and word for word
+    system = PIMSystem(DPUConfig(**dpu))
+    st, _ = hbmpim.launch_commands(system, "hand", _crf(hand),
+                                   np.array([bank], np.int32),
+                                   np.array(srf, np.int32))
+    assert (int(st["cycle"][0]), int(st["c_issued"][0])) == (88, 55)
+    assert st["mram"][0].tolist() == got
+
+
+def _tiny_gemvs(**over) -> spec.Cell:
+    """GEMVS in all-bank mode on 4 banks; 65 CRF slots split each chunk
+    of 9 groups of 16 rows into launches of 8 groups and of 1."""
+    return spec.Cell(
+        name="tiny.gemvs-hbm", chips=1,
+        config={"dpu": _hbm_dpu(n_dpus=4, hbm_crf_slots=65, **over),
+                "timing_model": "hbm_bank"},
+        traffic={"workload": "GEMVS", "scale": 0.075, "mram_bytes": 1 << 16,
+                 "seed_pool": 1, "sizes": {"rows": 144}},
+        end_to_end=[], per_layer=[])
+
+
+def test_hbm_bank_model_matches_the_program_on_every_bank():
+    """A tiny GEMVS in all-bank mode: every launch's cycles and issued
+    commands on every bank, and the words the last launch leaves."""
+    cell = _tiny_gemvs()
+    sim = harness.Sim(data_seed=3)
+    harness.Program(cell).simulate(sim)
+    assert sim.error is None, sim.error
+    dpu, sizes = cell.dpu(), cell.traffic["sizes"]
+    want = cell.reference().launches(dpu, sizes, 3)
+    # two launches per chunk of eight columns
+    assert len(want) == len(sim.launches) == 16
+    for launch, got in zip(want, sim.launches):
+        for k in range(4):
+            assert hbm_bank.time_launch(dpu, "GEMVS", launch, k) == \
+                (int(got.cycles[k]), int(got.issued[k]))
+    cmds, srf, _ = want[-1]
+    bank = hbm_bank.bank_words(dpu, want[-1], 2)
+    hbm_bank.run(cmds, srf[2].tolist(), bank, dpu=dpu)
+    assert bank[:sim.image.shape[1]].tolist() == sim.image[2].tolist()
+
+
+@pytest.mark.parametrize("backend, workload, warmup", [
+    ("", "VA", "kernel"), ("scalar", "BFS", "kernel"),
+    ("hbmpim", "VA", "kernel"), ("hbmpim", "GEMVS", "crf"),
+    ("hbmpim_cmd", "GEMVS", "crf")])
+def test_warmup_follows_the_backend(backend, workload, warmup):
+    cell = spec.load_cell("rank64.va")
+    cell.config = {**cell.config,
+                   "dpu": {**cell.config["dpu"], "backend": backend}}
+    cell.traffic = {**cell.traffic, "workload": workload}
+    assert Path(cell.warmup().__file__).stem == warmup
+
+
+@pytest.mark.parametrize("backend", ["hbmpim", "hbmpim_cmd"])
+def test_crf_warmup_builds_the_keys_the_launches_use(backend, monkeypatch):
+    """The command-engine executables that the warm-up builds are those
+    that the simulation's launches then look up, and no others."""
+    from repro.core import compile_cache
+    used, inner = [], compile_cache._get_entry
+
+    def get_entry(*args):
+        entry, hit = inner(*args)
+        used.append(entry.key)
+        return entry, hit
+
+    monkeypatch.setattr(compile_cache, "_ENTRIES", {})
+    monkeypatch.setattr(compile_cache, "_get_entry", get_entry)
+    prog = harness.Program(_tiny_gemvs(backend=backend))
+    warmed = prog.prewarm()
+    assert len(set(warmed)) == 2, "one per program length"
+    del used[:]
+    misses = compile_cache.stats()["misses"]
+    sim = harness.Sim(data_seed=1)
+    prog.simulate(sim)
+    assert sim.error is None, sim.error
+    assert len(used) == 16 and set(used) == set(warmed)
+    assert compile_cache.stats()["misses"] == misses

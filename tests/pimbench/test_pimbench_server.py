@@ -51,14 +51,15 @@ def test_server_config_is_the_rank_at_server_width():
     assert {**rank["dpu"], "n_dpus": 2560, "n_ranks": 40} == server["dpu"]
     cfg = DPUConfig(**spec.load_cell("server2560.va").dpu())
     assert cfg.n_dpus // cfg.n_ranks == 64
-    assert compile_cache.dpu_bucket(cfg.n_dpus) == 4096
+    assert compile_cache.dpu_bucket(cfg.n_dpus) == 2560
 
 
 @pytest.fixture(scope="module")
 def small_server():
     """The server cell at 10 DPUs over 5 ranks, padded to 16 lanes (the
-    server's 2,560 of 4,096): one traced simulation through the
-    harness, with its data seed's statistics pinned by a run before."""
+    server's own 2,560 DPUs fill their 2,560 lanes): one traced
+    simulation through the harness, with its data seed's statistics
+    pinned by a run before."""
     cell = _cut(spec.load_cell("server2560.va"), n_dpus=10, n_ranks=5)
     ds = int(window.data_seeds(SEED, int(cell.traffic["seed_pool"]))[0])
     pin = harness.Sim(data_seed=ds)
